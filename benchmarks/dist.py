@@ -31,7 +31,7 @@ import subprocess
 import sys
 import textwrap
 
-from benchmarks.common import row
+from benchmarks.common import refuse_on_tpu, row
 from benchmarks.engines import annotate
 
 DISTS = ("uniform", "zipf", "clustered")
@@ -93,6 +93,7 @@ def _collect_ndev(ndev: int, ns, dists) -> dict:
 
 
 def collect(fast: bool = True, smoke: bool = False) -> dict:
+    refuse_on_tpu("benchmarks.dist")
     if smoke:
         ndevs, ns, dists = (8,), (1 << 12,), ("uniform",)
     elif fast:

@@ -3,8 +3,9 @@
 The distributed sort over 8 fake devices is the TPU analogue of the PCIe
 pipeline: local sort / all_to_all exchange / merge, with chunk count s
 controlling the overlap window.  Runs in a subprocess so the 8-device flag
-never touches the parent process.  Reports wall-clock plus the paper's
-pipeline model T = T_x/s + max(T_x, T_s, T_m) + ... as derived columns.
+never touches the parent process — a CPU rehearsal, refused on a TPU
+host.  Reports wall-clock plus the paper's pipeline model
+T = T_x/s + max(T_x, T_s, T_m) + ... as derived columns.
 """
 from __future__ import annotations
 
@@ -12,7 +13,7 @@ import subprocess
 import sys
 import textwrap
 
-from benchmarks.common import row
+from benchmarks.common import refuse_on_tpu, row
 
 SCRIPT = textwrap.dedent("""
     import os
@@ -40,6 +41,7 @@ SCRIPT = textwrap.dedent("""
 
 
 def main(fast: bool = True):
+    refuse_on_tpu("benchmarks.fig8_pipeline")
     n = 1 << 18 if fast else 1 << 21
     res = subprocess.run([sys.executable, "-c", SCRIPT, str(n)],
                          capture_output=True, text=True, timeout=1200)

@@ -33,6 +33,9 @@ BENCH_hybrid.json (``ratios/entropy/.../adaptive`` > 1 means pass elision
 pays; the gate is >= 1.3x on low-entropy rungs, <= 1.05x regression on
 uniform).
 
+A module that raises prints ``<name>/ERROR`` and the runner exits non-zero
+after the sweep.
+
 ``python -m benchmarks.run [--full] [--smoke] [--only fig6,...]
                            [--json [PATH]] [--entropy] [--ooc] [--spill]
                            [--faults] [--dist]``
@@ -82,6 +85,10 @@ def main() -> None:
     if args.smoke and only is None:
         only = ["engines"]               # smoke: the acceptance-gated sweep
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
+    failed = []
     print("name,us_per_call,derived")
     for name in MODULES:
         if only and not any(name.startswith(o) for o in only):
@@ -97,6 +104,7 @@ def main() -> None:
         except Exception as e:
             traceback.print_exc(file=sys.stderr)
             print(f"{name}/ERROR,0.0,{type(e).__name__}")
+            failed.append(name)
 
     def dump(rows, path):
         with open(path, "w") as f:
@@ -137,6 +145,11 @@ def main() -> None:
         if args.json is not None:
             dump(rows, os.path.join(os.path.dirname(args.json) or ".",
                                     "BENCH_dist.json"))
+
+    if failed:
+        print(f"# {len(failed)} benchmark module(s) failed: "
+              f"{', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -65,7 +65,7 @@ def hybrid_params(n: int, cfg: model.SortConfig, key_bits: int = 32,
         "passes": model.num_digits(key_bits, cfg.d),
         "g_max": plan.max_region_blocks(n, cfg.kpb, a_max),
         "B": cfg.step_batch,
-        "n_pad": fused.pad_length(n, cfg.kpb),
+        "n_pad": fused.buffer_length(n, cfg.kpb),
         "kb": key_bytes, "vb": val_bytes, "vals": vals,
     }
 
@@ -79,7 +79,7 @@ def lsd_params(n: int, d: int, kpb: int, step_batch: int, key_bits: int = 32,
         "passes": model.num_digits(key_bits, d),
         "g_max": plan.max_region_blocks(n, kpb, 1),
         "B": step_batch,
-        "n_pad": fused.pad_length(n, kpb),
+        "n_pad": fused.buffer_length(n, kpb),
         "kb": key_bytes, "vb": val_bytes, "vals": vals,
     }
 
@@ -97,7 +97,7 @@ def spp_params(m: int, num_buckets: int, kpb: int = 1024,
         "passes": 1,
         "g_max": plan.max_region_blocks(m, kpb_eff, 1),
         "B": step_batch,
-        "n_pad": fused.pad_length(m, kpb_eff),
+        "n_pad": fused.buffer_length(m, kpb_eff),
         "kb": id_bytes, "vb": 4, "vals": 1,
     }
 
@@ -165,10 +165,7 @@ class Contract:
 
 
 def _abstract_mesh(n: int, name: str):
-    try:
-        return jax.sharding.AbstractMesh((n,), (name,))
-    except TypeError:                       # older ctor: ((name, size),)
-        return jax.sharding.AbstractMesh(((name, n),))
+    return jax.sharding.AbstractMesh((n,), (name,))
 
 
 def _mk_hybrid():
@@ -344,7 +341,7 @@ def table_checks() -> Dict[str, List[str]]:
         jnp.zeros((1,), jnp.int32), jnp.full((1,), m, jnp.int32), m, kpb,
         plan.max_region_blocks(m, kpb, 1), batch=B)
     out["hazard.fused_tables"] = refhazard.check_fused_tables(
-        blocks, m, kpb, fused.pad_length(m, kpb))
+        blocks, m, kpb, fused.buffer_length(m, kpb))
 
     lens, kway, tile = (64, 48, 32, 16, 40), 4, 16
     n = int(sum(lens))

@@ -12,11 +12,12 @@ traffic.  This pass makes that failure loud:
     mapping must carry exactly the declared number of alias pairs,
   * structural checks on every alias pair — operand/output avals match and
     the aliased operand has zero ``get``s in the kernel body,
-  * the silent-copy sweep — any *unaliased* 1-D output at least as large as
-    the site's largest buffer operand, with an identically-shaped unaliased
-    operand available to donate, is flagged (that is exactly the shape of a
-    forgotten ping-pong alias; accumulator outputs and the 2-D bitonic
-    class tables don't trip it).
+  * the silent-copy sweep — any *unaliased* flat 1-D or whole-HBM
+    (``memory_space=ANY``) output at least as large as the site's largest
+    buffer operand, with an identically-shaped unaliased operand available
+    to donate, is flagged (that is exactly the shape of a forgotten
+    ping-pong alias; accumulator outputs and the tiled bitonic class tables
+    don't trip it).
 """
 from __future__ import annotations
 
@@ -63,7 +64,8 @@ def audit_site(site: PallasSite) -> List[str]:
         aliased_ops = set(site.aliases)
         aliased_outs = set(site.aliases.values())
         for j, oav in enumerate(site.out_avals):
-            if j in aliased_outs or len(oav.shape) != 1:
+            if j in aliased_outs or not (len(oav.shape) == 1 or
+                                         site.output_in_hbm(j)):
                 continue
             if _nbytes(oav) < buf_max:
                 continue

@@ -25,19 +25,20 @@ from __future__ import annotations
 
 from typing import Dict, List, Sequence
 
-import numpy as np
-
 import jax
+import numpy as np
+from jax.extend.core import Literal
 
 from repro.analysis.trace import PallasSite, ref_events
+from repro.kernels import fused
 
 
 def _is_static(x) -> bool:
-    return isinstance(x, (int, np.integer)) or isinstance(x, jax.core.Literal)
+    return isinstance(x, (int, np.integer)) or isinstance(x, Literal)
 
 
 def _static_val(x):
-    return int(x.val) if isinstance(x, jax.core.Literal) else int(x)
+    return int(x.val) if isinstance(x, Literal) else int(x)
 
 
 def _ref_shape(site: PallasSite, root: int):
@@ -69,10 +70,11 @@ def check_kernel(site: PallasSite) -> List[str]:
             findings.append(
                 f"{label}: {len(scatter_writes)} scatter writes in one grid "
                 f"step — write-after-write hazard")
-        # (1c) read-after-(dynamic-)write within the super-step
+        # (1c) read-after-(dynamic-)write within the super-step, on the
+        # kernel's operands (VMEM/SMEM scratch is program-ordered staging)
         dirty_at = min((e.order for e in evs
                         if e.kind == "swap" and e.dynamic), default=None)
-        if dirty_at is not None:
+        if dirty_at is not None and kind != "scratch":
             late = [e for e in gets if e.order > dirty_at]
             if late:
                 findings.append(
@@ -140,8 +142,9 @@ def check_fused_tables(blocks, n: int, kpb: int, n_pad: int) -> List[str]:
     """Interval analysis on a fused-launch descriptor table instance.
 
     ``blocks`` is a ``plan.RegionBlocks`` (flat or packed); rows flatten in
-    descriptor order.  Proves: (a) each row's block load ``[off, off + kpb)``
-    fits the padded buffer, (b) rows group into regions at ``reset`` flags
+    descriptor order.  Proves: (a) each row's block load — the
+    ``fused.window_lines(kpb)`` 128-key lines from the line holding ``off``
+    — fits the padded buffer, (b) rows group into regions at ``reset`` flags
     whose write intervals ``[first_offset, first_offset + Σcount)`` are
     pairwise disjoint and tile ``[0, n)`` exactly — the exactly-once scatter
     coverage of one fused pass.
@@ -151,11 +154,13 @@ def check_fused_tables(blocks, n: int, kpb: int, n_pad: int) -> List[str]:
                                            blocks.reset, blocks.count,
                                            blocks.active))
     findings: List[str] = []
+    window = fused.window_lines(kpb) * fused.LANES
     for g in range(off.shape[0]):
-        if int(off[g]) < 0 or int(off[g]) + kpb > n_pad:
+        lo = int(off[g]) - int(off[g]) % fused.LANES
+        if int(off[g]) < 0 or lo + window > n_pad:
             findings.append(
-                f"fused tables: row {g} loads [{int(off[g])}, "
-                f"{int(off[g]) + kpb}) outside padded buffer [0, {n_pad})")
+                f"fused tables: row {g} loads [{lo}, {lo + window}) outside "
+                f"padded buffer [0, {n_pad})")
     regions = []
     cur = None
     for g in range(off.shape[0]):
@@ -234,8 +239,9 @@ def check_block_injectivity(site: PallasSite,
     bms = site.block_mappings()
     out_bms = bms[-site.num_outputs:] if site.num_outputs else ()
     for j, bm in enumerate(out_bms):
-        arr_shape = tuple(int(d) for d in bm.array_shape_dtype.shape)
-        blk_shape = tuple(int(d) for d in bm.block_shape)
+        arr_shape = tuple(int(d) for d in bm.array_aval.shape)
+        blk_shape = tuple(int(getattr(d, "block_size", d))
+                          for d in bm.block_shape)
         if blk_shape == arr_shape:
             continue
         imj = bm.index_map_jaxpr

@@ -8,9 +8,11 @@ executing it.  This module turns a (Closed)Jaxpr into:
     avals, scalar-prefetch split and ``input_output_aliases`` — the raw
     material for the census, donation, transfer and ref-hazard passes,
   * per-kernel ref access summaries (:func:`ref_access_counts`,
-    :func:`ref_events`): every ``get``/``swap`` on a kernel operand ref, in
-    program order, classified static vs dynamic by recovering the
-    ``NDIndexer`` the Pallas tracer flattened into the equation.  Accesses
+    :func:`ref_events`): every ``get``/``swap`` on a kernel operand ref —
+    and every ``dma_start``, a read of its source and a write of its
+    destination — in program order, classified static vs dynamic by
+    recovering the ``NDIndexer`` the Pallas tracer flattened into the
+    equation.  Accesses
     inside sub-jaxprs (``pl.when`` conds, inner loops) are attributed to the
     outer kernel ref through an invar environment.
   * collective-primitive shapes (:func:`collective_link_bytes`) with the
@@ -24,6 +26,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
+from jax.extend.core import Literal
 
 
 def _unwrap(jaxpr):
@@ -36,7 +39,7 @@ def _aval(var):
 
 
 def _is_literal(x) -> bool:
-    return isinstance(x, jax.core.Literal) or not hasattr(x, "aval")
+    return isinstance(x, Literal) or not hasattr(x, "aval")
 
 
 @dataclass
@@ -53,7 +56,7 @@ class RefEvent:
 class PallasSite:
     """One ``pallas_call`` equation with its analysis-relevant structure."""
     name: str                    # kernel function name
-    src: str                     # full name_and_src_info string
+    src: str                     # "<kernel> at <file>:<line>"
     grid: Tuple[int, ...]
     in_while: bool               # inside any while-loop body
     num_scalars: int             # scalar-prefetch operands (index space head)
@@ -91,6 +94,12 @@ class PallasSite:
     def block_mappings(self):
         return tuple(self.eqn.params["grid_mapping"].block_mappings)
 
+    def output_in_hbm(self, j: int) -> bool:
+        """Output ``j`` stays in HBM as a whole (``memory_space=ANY``): the
+        kernel moves it by DMA — the ping-pong buffer layout."""
+        bm = self.block_mappings()[self.num_inputs + j]
+        return str(getattr(bm.block_aval, "memory_space", "")) == "any"
+
 
 def _sub_jaxprs_with_env(eqn):
     """Yield (sub_jaxpr, operand_list) pairs mapping sub invars to outer vars.
@@ -99,10 +108,18 @@ def _sub_jaxprs_with_env(eqn):
     entries may be ``None`` where no outer var corresponds (e.g. consts).
     Handles the primitives that appear inside Pallas kernel bodies: ``cond``
     (operands follow the predicate), ``while`` (cond consts, body consts,
-    carry), ``scan``/``pjit``/``closed_call`` (1:1), with a zip fallback.
+    carry), ``run_scoped`` (outer refs bind the body's constvars, then its
+    scoped allocations), ``scan``/``pjit``/``closed_call`` (1:1), with a zip
+    fallback.
     """
     name = eqn.primitive.name
     params = eqn.params
+    if name == "run_scoped":
+        # the body closes over the outer refs as constvars; its invars are
+        # the scoped allocations (no outer counterpart)
+        sub = _unwrap(params["jaxpr"])
+        yield sub, list(eqn.invars) + [None] * len(sub.invars)
+        return
     if name == "cond":
         ops = list(eqn.invars[1:])
         for br in params["branches"]:
@@ -134,7 +151,8 @@ def collect_pallas_sites(jaxpr, _in_while: bool = False) -> List[PallasSite]:
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             gm = eqn.params["grid_mapping"]
-            nsi = str(eqn.params.get("name_and_src_info", ""))
+            dbg = getattr(eqn.params["jaxpr"], "debug_info", None)
+            nsi = str(getattr(dbg, "func_src_info", "") or "")
             raw = eqn.params.get("input_output_aliases", ())
             out.append(PallasSite(
                 name=nsi.split(" at ")[0] if nsi else "<pallas>",
@@ -204,6 +222,18 @@ def _safe_aval(x):
         return None
 
 
+def _dma_operands(eqn):
+    """[("get", src_ref, src_indexers), ("swap", dst_ref, dst_indexers)] of
+    a ``dma_start`` equation (its flattened operand tree leads with the
+    source ref and transforms, then the destination's)."""
+    ops = jax.tree_util.tree_unflatten(eqn.params["tree"], eqn.invars)
+    out = []
+    for kind, ref, tr in (("get", ops[0], ops[1]), ("swap", ops[2], ops[3])):
+        tr = tr if isinstance(tr, (tuple, list)) else (tr,)
+        out.append((kind, ref, tuple(t for t in tr if hasattr(t, "indices"))))
+    return out
+
+
 def ref_events(kernel_jaxpr) -> Dict[int, List[RefEvent]]:
     """Program-ordered get/swap events per kernel operand-ref index.
 
@@ -217,6 +247,18 @@ def ref_events(kernel_jaxpr) -> Dict[int, List[RefEvent]]:
 
     def walk(j, env):
         for eqn in j.eqns:
+            if eqn.primitive.name == "dma_start":
+                # a DMA reads its source ref and writes its destination ref
+                counter[0] += 1
+                for kind, ref, tr in _dma_operands(eqn):
+                    root = env.get(id(ref))
+                    if root is not None:
+                        dyn = any(not _is_literal(getattr(c, "start", c))
+                                  for nd in tr for c in nd.indices)
+                        events.setdefault(root, []).append(RefEvent(
+                            kind=kind, order=counter[0], dynamic=dyn,
+                            scatter=False, indexer=tr))
+                continue
             if eqn.primitive.name in ("get", "swap"):
                 root = env.get(id(eqn.invars[0]))
                 counter[0] += 1
@@ -229,7 +271,10 @@ def ref_events(kernel_jaxpr) -> Dict[int, List[RefEvent]]:
                 continue
             for sub, ops in _sub_jaxprs_with_env(eqn):
                 sub_env = {}
-                for iv, ov in zip(sub.invars, ops):
+                binders = (list(sub.constvars) + list(sub.invars)
+                           if eqn.primitive.name == "run_scoped"
+                           else sub.invars)
+                for iv, ov in zip(binders, ops):
                     if ov is not None and id(ov) in env:
                         sub_env[id(iv)] = env[id(ov)]
                 if sub_env:

@@ -340,13 +340,8 @@ def valid_concat(out, valid):
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (older ones: experimental module)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # --- contract declaration (verified by repro.analysis; see analysis/contracts)

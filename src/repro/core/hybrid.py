@@ -63,10 +63,10 @@ import numpy as np
 from jax import lax
 
 from repro.core import bijection, model, plan
-from repro.core.ranks import stable_partition_dest
+from repro.core.ranks import (resolve_engine, resolve_interpret,
+                              stable_partition_dest)
 from repro.kernels import fused
-from repro.kernels.ops import (apply_run_copies, local_sort_class_plan,
-                               segmented_local_sort)
+from repro.kernels.ops import local_sort_class_plan, segmented_local_sort
 
 
 class SortStats(NamedTuple):
@@ -198,13 +198,13 @@ def _counting_pass_fused(state, *, k, d, lo, a_max, g_max, n, nd, cfg,
         if adaptive:
             nk, nv, h1, h2 = fused.fused_counting_pass(
                 ck, cv, ak, av, sc, *blocks, dest_base, nsid,
-                kpb=cfg.kpb, r=r, a_max=a_max, n=n, interpret=interpret,
+                kpb=cfg.kpb, r=r, a_max=a_max, interpret=interpret,
                 lookahead=True)
             return (nk, nv, ck, cv, h1.reshape(a_max, r),
                     h2.reshape(a_max, r), p + 2 < nd, p_exec + 1, n_eld)
         nk, nv, h1 = fused.fused_counting_pass(
             ck, cv, ak, av, sc, *blocks, dest_base, nsid,
-            kpb=cfg.kpb, r=r, a_max=a_max, n=n, interpret=interpret)
+            kpb=cfg.kpb, r=r, a_max=a_max, interpret=interpret)
         return (nk, nv, ck, cv, h1.reshape(a_max, r), hist_nxt, nxt_valid,
                 p_exec + 1, n_eld)
 
@@ -259,9 +259,8 @@ def _local_sort_kernel(ukeys, vals, seg_id, done, *, s_max, row_len, classes,
     ends = jnp.concatenate([starts[1:], jnp.array([n], jnp.int32)])
     sizes = ends - starts                                     # 0 on padding rows
     sortable = done[jnp.clip(starts, 0, n - 1)] & (starts < n)
-    src, dst = segmented_local_sort(ukeys, starts, sizes, sortable, row_len,
-                                    interpret=interpret, classes=classes)
-    return apply_run_copies(src, dst, (ukeys, vals))
+    return segmented_local_sort((ukeys, vals), starts, sizes, sortable,
+                                row_len, interpret=interpret, classes=classes)
 
 
 def _local_row_len(n: int, cfg: model.SortConfig) -> int:
@@ -284,8 +283,8 @@ def local_sort_classes(n: int, cfg: model.SortConfig):
                                              "max_passes", "engine",
                                              "interpret", "lo", "adaptive"))
 def _hybrid_sort_bits(ukeys, vals, cfg: model.SortConfig, k: int,
-                      return_stats: bool, max_passes: Optional[int] = None,
-                      engine: str = "argsort", interpret: bool = True,
+                      return_stats: bool, max_passes: Optional[int],
+                      engine: str, interpret: bool,
                       lo: int = 0, adaptive: bool = False):
     n = ukeys.shape[0]
     d = cfg.d
@@ -307,8 +306,7 @@ def _hybrid_sort_bits(ukeys, vals, cfg: model.SortConfig, k: int,
         # the one unfused sweep of the sort: pass 0's histogram (§4.3)
         w0 = min(d, max(k - lo, 1))
         seg_hist0 = fused.initial_histogram(ck, n, max(k - w0, 0), w0, r,
-                                            a_max, cfg.kpb,
-                                            interpret=interpret)
+                                            a_max, interpret=interpret)
 
         def cond(state):
             done, p = state[5], state[9]
@@ -323,8 +321,8 @@ def _hybrid_sort_bits(ukeys, vals, cfg: model.SortConfig, k: int,
                            (ck, cv, ak, av, seg0, done0, seg_hist0,
                             jnp.zeros_like(seg_hist0), nxt_valid0,
                             z, z, z))
-        ukeys = ck[:n]
-        vals = jax.tree.unflatten(treedef, [v[:n] for v in cv])
+        ukeys = fused.unpad(ck, n, ukeys.dtype)
+        vals = jax.tree.unflatten(treedef, [fused.unpad(v, n) for v in cv])
     else:
         def cond(state):
             done, p = state[3], state[5]
@@ -372,13 +370,12 @@ def hybrid_sort(keys: jnp.ndarray, values: Any = None,
     histogram on donated ping-pong buffers — plus the bitonic local sort),
     ``"argsort"`` (fused XLA stable sorts), or ``"scan"`` (the O(n) chunked
     jnp fallback).  ``None`` defers to ``cfg.rank_engine`` (``"auto"`` by
-    default); ``"auto"`` resolves per backend with the hardware demotion
-    rule of ``core.plan.resolve_pass_engine`` — the fused ``kernel`` engine
-    wherever Pallas runs in interpret mode, ``argsort`` on compiled
-    hardware until the fused kernel's Mosaic lowering lands (an explicit
-    ``engine="kernel"`` is always honoured).  All engines produce
-    byte-identical output.  ``interpret`` forces Pallas interpret mode (on
-    by default off-TPU).
+    default); ``"auto"`` resolves per backend (``core.ranks.resolve_engine``)
+    — the Mosaic-compiled ``kernel`` engine on a TPU, ``argsort`` elsewhere.
+    All engines produce byte-identical output.  ``interpret`` forces Pallas
+    interpret mode (``None``: on iff the backend is not a TPU).  The TPU
+    kernel path sorts keys of at most 32 bits; 64-bit keys there raise and
+    sort with ``engine="argsort"``.
 
     ``adaptive`` enables the entropy-adaptive schedule (``None`` defers to
     ``cfg.adaptive``, on by default): concrete keys get a statically
@@ -394,18 +391,15 @@ def hybrid_sort(keys: jnp.ndarray, values: Any = None,
     """
     if keys.ndim != 1:
         raise ValueError("hybrid_sort expects a 1-D key array")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     k = bijection.key_bits(keys.dtype)
     if k > 32 and not jax.config.jax_enable_x64:
         raise RuntimeError("64-bit keys require jax_enable_x64")
     cfg = cfg or model.default_config(k // 8)
     if adaptive is None:
         adaptive = cfg.adaptive
-    # explicit argument > cfg.rank_engine > backend default (with the
-    # interpret-only demotion of auto-resolved "kernel", see core.plan)
-    engine = plan.resolve_pass_engine(
-        engine if engine is not None else cfg.rank_engine, interpret)
+    # explicit argument > cfg.rank_engine > backend default
+    engine = resolve_engine(engine if engine is not None else cfg.rank_engine)
     n = keys.shape[0]
     if n == 0:
         out = (keys, values) if values is not None else keys
@@ -434,6 +428,8 @@ def hybrid_sort(keys: jnp.ndarray, values: Any = None,
             lo, hi = live_bit_window(bijection.to_ordered_bits_np(
                 np.asarray(keys)))
 
+    if engine == "kernel":
+        fused.require_kernel_keys(ukeys.dtype, keys.dtype, interpret)
     vals = values if values is not None else ()
     ukeys, vals, stats = _hybrid_sort_bits(ukeys, vals, cfg, hi, return_stats,
                                            max_passes, engine, interpret,
@@ -449,7 +445,7 @@ def hybrid_sort(keys: jnp.ndarray, values: Any = None,
 # --- contract declaration (verified by repro.analysis; see analysis/contracts)
 # Formulas are symbolic in the structural parameters the analyzer derives per
 # (n, cfg): classes = len(local_sort_classes(n, cfg)), passes = ⌈k/d⌉ nominal
-# schedule slots, n_pad = fused.pad_length(n, cfg.kpb), kb/vb = key/value
+# schedule slots, n_pad = fused.buffer_length(n, cfg.kpb), kb/vb = key/value
 # bytes, vals = payload leaves, g_max/B = descriptor rows / super-step width.
 ANALYSIS_CONTRACT = {
     "entry": "repro.core.hybrid.hybrid_sort",

@@ -24,7 +24,8 @@ import numpy as np
 from jax import lax
 
 from repro.core import bijection, hybrid, model, plan
-from repro.core.ranks import stable_partition_dest
+from repro.core.ranks import (resolve_engine, resolve_interpret,
+                              stable_partition_dest)
 from repro.kernels import fused
 
 
@@ -53,18 +54,19 @@ def _lsd_sort_bits(ukeys, vals, d: int, k: int, engine: str, kpb: int,
                                          batch=step_batch)
         nsid = jnp.zeros((r,), jnp.int32)     # every sub-bucket -> segment 0
         w0 = min(d, max(k - lo, 1))
-        seg_hist = fused.initial_histogram(ck, n, lo, w0, r, 1, kpb,
+        seg_hist = fused.initial_histogram(ck, n, lo, w0, r, 1,
                                            interpret=interpret)
         for p in range(nd):
             base_excl = jnp.cumsum(seg_hist, axis=1) - seg_hist
             sc = plan.lsd_digit_window(p, k, d, lo=lo)
             nk, nv, hist_next = fused.fused_counting_pass(
                 ck, cv, ak, av, sc, *blocks, base_excl, nsid,
-                kpb=kpb, r=r, a_max=1, n=n, interpret=interpret)
+                kpb=kpb, r=r, a_max=1, interpret=interpret)
             # flip: written buffers become current, old ones donate next
             ak, av, ck, cv = ck, cv, nk, nv
             seg_hist = hist_next.reshape(1, r)
-        return ck[:n], jax.tree.unflatten(treedef, [v[:n] for v in cv])
+        return (fused.unpad(ck, n, udt),
+                jax.tree.unflatten(treedef, [fused.unpad(v, n) for v in cv]))
 
     def body(p, state):
         ukeys, vals = state
@@ -102,10 +104,8 @@ def lsd_sort(keys: jnp.ndarray, values: Any = None, d: int = 5,
     """
     if keys.ndim != 1:
         raise ValueError("lsd_sort expects a 1-D key array")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    # auto-resolved "kernel" engages under interpret mode only (core.plan)
-    engine = plan.resolve_pass_engine(engine, interpret)
+    interpret = resolve_interpret(interpret)
+    engine = resolve_engine(engine)
     k = bijection.key_bits(keys.dtype)
     if keys.shape[0] == 0:
         out = keys if values is None else (keys, values)
@@ -117,6 +117,8 @@ def lsd_sort(keys: jnp.ndarray, values: Any = None, d: int = 5,
         lo, hi = hybrid.live_bit_window(bijection.to_ordered_bits_np(
             np.asarray(keys)))
     ukeys = bijection.to_ordered_bits(keys)
+    if engine == "kernel":
+        fused.require_kernel_keys(ukeys.dtype, keys.dtype, interpret)
     vals = values if values is not None else ()
     ukeys, vals = _lsd_sort_bits(ukeys, vals, d, hi, engine, kpb, step_batch,
                                  interpret, lo=lo)

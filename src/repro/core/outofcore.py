@@ -94,9 +94,9 @@ from repro.core.faults import (ChecksumError, FaultLedger, FaultPolicy,
                                RetriesExhausted, RetryPolicy, guarded,
                                tree_checksums)
 from repro.core.hybrid import hybrid_sort
-from repro.core.ranks import resolve_engine
+from repro.core.ranks import resolve_engine, resolve_interpret
 from repro.kernels import merge as kmerge
-from repro.kernels.fused import pad_length
+from repro.kernels.fused import buffer_length, pad_length
 
 # Modeled peak device working set, in units of one chunk / one slab payload.
 # Chunk phase: staged chunks i and i+1, the sort's ping-pong pair (2x), the
@@ -110,8 +110,9 @@ def _chunk_working_bytes(chunk_elems: int, elem_bytes: int, cfg, engine,
                          key_dtype) -> int:
     """Modeled device working set of one chunk sort (its ping-pong pair).
 
-    The kernel engine's donated ping-pong buffers are ``pad_length(n, kpb)``
-    long — whole KPB tiles plus a spare — which dwarfs the raw chunk bytes
+    The kernel engine's donated ping-pong buffers are
+    ``buffer_length(n, kpb)`` long — whole 128-key lines plus one block
+    window — which dwarfs the raw chunk bytes
     for small chunks under a large ``kpb``; the jnp engines work in n-sized
     buffers.  Mirrors ``hybrid_sort``'s cfg/engine resolution so the spill
     budget clamp and the ledger charge what the sort actually allocates.
@@ -119,7 +120,7 @@ def _chunk_working_bytes(chunk_elems: int, elem_bytes: int, cfg, engine,
     if resolve_engine(engine) == "kernel":
         kpb = (cfg or model.default_config(
             bijection.key_bits(key_dtype) // 8)).kpb
-        return 2 * pad_length(chunk_elems, kpb) * elem_bytes
+        return 2 * buffer_length(chunk_elems, kpb) * elem_bytes
     return 2 * chunk_elems * elem_bytes
 
 
@@ -314,7 +315,7 @@ def _sort_chunk(keys, leaves, cfg, engine, interpret):
                                              "interpret"),
                    donate_argnums=(2, 3))
 def merge_round(src_keys, src_vals, alt_keys, alt_vals, *, lens, kway: int,
-                tile: int, n: int, interpret: bool = True):
+                tile: int, n: int, interpret: bool):
     """One k-way merge round: diagonal partition + ONE merge-kernel launch.
 
     ``lens`` is the static tuple of current run lengths; groups of up to
@@ -787,8 +788,7 @@ def oocsort(reader, chunk_elems: int, values: Any = None,
     """
     if checkpoint_every < 1:
         raise ValueError("checkpoint_every must be >= 1")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     faultlog = FaultLedger()
     ledger = _DeviceLedger()
 
@@ -891,7 +891,7 @@ def oocsort(reader, chunk_elems: int, values: Any = None,
                     f"{_spill_peak_bytes(tile, tile, elem_bytes, kway)} "
                     f"for tile={tile} (worst-case stream of one-tile slabs)")
             # largest chunk whose engine-aware peak (kernel chunks allocate
-            # pad_length(n, kpb)-sized ping-pong pairs) fits the budget
+            # buffer_length(n, kpb)-sized ping-pong pairs) fits the budget
             peak = lambda c: _chunk_peak_bytes(c, elem_bytes, cfg, engine,
                                                key_dtype)
             if peak(1) > spill_budget_bytes:
@@ -900,7 +900,7 @@ def oocsort(reader, chunk_elems: int, values: Any = None,
                     f"the chunk phase: even a 1-element chunk sort models "
                     f"{peak(1)} device bytes (engine "
                     f"{resolve_engine(engine)!r}; the kernel engine pads to "
-                    f"whole cfg.kpb tiles — pass a smaller-kpb cfg)")
+                    f"a cfg.kpb block window — pass a smaller-kpb cfg)")
             lo = 1
             hi = max(1, spill_budget_bytes // (_CHUNK_FOOTPRINT * elem_bytes))
             while peak(hi) <= spill_budget_bytes and hi < chunk_elems:

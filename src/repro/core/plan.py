@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.ranks import (invert_permutation, resolve_engine,
-                              stable_partition_dest)
+                              resolve_interpret, stable_partition_dest)
 from repro.kernels import fused
 
 
@@ -61,24 +61,6 @@ class RegionBlocks(NamedTuple):
     reset: jnp.ndarray   # (G,) 1 = first block of its region (carry reset)
     count: jnp.ndarray   # (G,) live lanes in the block
     active: jnp.ndarray  # (G,) 1 = partition block, 0 = copy-through block
-
-
-def resolve_pass_engine(engine, interpret: bool) -> str:
-    """Resolve an engine name with the hardware demotion rule.
-
-    ``None``/``"auto"`` resolves per backend (``ranks.resolve_engine``), but
-    an *auto-resolved* ``kernel`` only engages under interpret mode: the
-    fused kernel's per-lane scatter stores are interpret-first until its
-    Mosaic lowering story lands (ROADMAP open item), so compiled-hardware
-    callers keep the XLA path unless they request ``engine="kernel"``
-    explicitly.  One rule for every consumer — the sort drivers,
-    ``single_pass_partition``, and everything above them.
-    """
-    auto = engine in (None, "auto")
-    engine = resolve_engine(engine)
-    if auto and engine == "kernel" and not interpret:
-        return "argsort"
-    return engine
 
 
 def digit_at(ukeys: jnp.ndarray, pass_idx, k: int, d: int,
@@ -323,13 +305,11 @@ def single_pass_partition(ids: jnp.ndarray, num_buckets: int,
     ``engine="kernel"`` runs ONE fused Pallas launch (plus the prologue
     histogram); the jnp engines use ``ranks.stable_partition_dest``.
 
-    Auto-resolved engines obey the ``resolve_pass_engine`` hardware demotion
-    rule (fused kernel under interpret only, until its Mosaic lowering
-    lands); ``engine="kernel"`` explicitly is always honoured.
+    ``None``/``"auto"`` resolves per backend (``ranks.resolve_engine``): the
+    fused kernel on a TPU, ``argsort`` elsewhere.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    engine = resolve_pass_engine(engine, interpret)
+    interpret = resolve_interpret(interpret)
+    engine = resolve_engine(engine)
     m = ids.shape[0]
     ids = ids.astype(jnp.int32)
     if m == 0 or engine != "kernel":
@@ -344,7 +324,7 @@ def single_pass_partition(ids: jnp.ndarray, num_buckets: int,
     kpb = max(8, min(kpb, 1 << (m - 1).bit_length()))   # one block if m small
     iota = jnp.arange(m, dtype=jnp.int32)
     (ck, cv), (ak, av) = fused.make_ping_pong(ids, (iota,), kpb)
-    hist0 = fused.initial_histogram(ck, m, 0, width, r, 1, kpb,
+    hist0 = fused.initial_histogram(ck, m, 0, width, r, 1,
                                     interpret=interpret)
     base_excl = jnp.cumsum(hist0, axis=1) - hist0            # base 0
     blocks = make_region_blocks(jnp.zeros((1,), jnp.int32),
@@ -355,8 +335,8 @@ def single_pass_partition(ids: jnp.ndarray, num_buckets: int,
     nsid = jnp.zeros((r,), jnp.int32)
     _, (perm_pad,), _ = fused.fused_counting_pass(
         ck, cv, ak, av, sc, *blocks, base_excl, nsid,
-        kpb=kpb, r=r, a_max=1, n=m, interpret=interpret)
-    perm = perm_pad[:m]
+        kpb=kpb, r=r, a_max=1, interpret=interpret)
+    perm = fused.unpad(perm_pad, m)
     dest = invert_permutation(perm)
     return dest, perm, hist0[0, :num_buckets]
 

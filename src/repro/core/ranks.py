@@ -50,6 +50,18 @@ def resolve_engine(engine=None, backend=None) -> str:
     return engine
 
 
+def resolve_interpret(interpret=None) -> bool:
+    """Pallas interpret mode: on iff the backend is not a TPU.
+
+    The one place the rule lives: every sort entry point resolves its
+    ``interpret`` argument here, and the kernel wrappers take it explicitly,
+    so no call on a TPU reaches a kernel in interpret mode unasked.
+    """
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
 def invert_permutation(perm: jnp.ndarray) -> jnp.ndarray:
     """dest[i] such that sorted[dest[i]] = x[i], given perm = argsort order."""
     n = perm.shape[0]

@@ -10,8 +10,8 @@ layer shares.  It is the core of:
   * the shard-partitioning step of the distributed sort (§5).
 
 ``engine=None`` resolves exactly like the sort drivers
-(``core.plan.resolve_pass_engine``: the fused Pallas ``kernel`` launch
-wherever Pallas interprets, ``argsort`` on compiled hardware).
+(``core.ranks.resolve_engine``: the fused Pallas ``kernel`` launch on a TPU,
+``argsort`` elsewhere).
 """
 from __future__ import annotations
 
@@ -49,7 +49,8 @@ class CapacityDispatch(NamedTuple):
 
 
 def capacity_dispatch(bucket_ids: jnp.ndarray, num_buckets: int, capacity: int,
-                      engine: Optional[str] = None) -> CapacityDispatch:
+                      engine: Optional[str] = None,
+                      interpret: Optional[bool] = None) -> CapacityDispatch:
     """Counting-sort dispatch into a dense (buckets, capacity) layout.
 
     This is the paper's scatter step with the destination chunk *reserved* per
@@ -57,7 +58,8 @@ def capacity_dispatch(bucket_ids: jnp.ndarray, num_buckets: int, capacity: int,
     beyond capacity are marked dropped (standard MoE semantics).
     """
     m = bucket_ids.shape[0]
-    part = counting_partition(bucket_ids, num_buckets, engine=engine)
+    part = counting_partition(bucket_ids, num_buckets, engine=engine,
+                              interpret=interpret)
     position = part.dest - part.offsets[bucket_ids]
     kept = position < capacity
     slot = jnp.where(kept, bucket_ids * capacity + position, num_buckets * capacity)

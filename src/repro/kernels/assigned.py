@@ -41,8 +41,8 @@ def _assigned_hist_kernel(tile_idx_ref, valid_ref, keys_ref, hist_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("shift", "width", "interpret"))
 def assigned_histogram(keys: jnp.ndarray, tile_idx: jnp.ndarray,
-                       valid: jnp.ndarray, shift: int, width: int,
-                       interpret: bool = True) -> jnp.ndarray:
+                       valid: jnp.ndarray, shift: int, width: int, *,
+                       interpret: bool) -> jnp.ndarray:
     """Histogram of data-dependent tile assignments.
 
     keys: (T, KPB); tile_idx: (G,) int32 — which tile grid step g reads

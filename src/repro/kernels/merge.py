@@ -348,7 +348,7 @@ def _kway_merge_kernel(off_ref, cnt_ref, wstart_ref, wtake_ref, *refs,
                                              "rank"))
 def kway_merge_round(src_keys, src_vals, alt_keys, alt_vals, out_off, out_cnt,
                      win_start, win_take, *, kway: int, tpb: int, n: int,
-                     interpret: bool = True, rank: str = "searchsorted"):
+                     interpret: bool, rank: str = "searchsorted"):
     """One k-way merge round over all groups in ONE Pallas launch.
 
     ``src_keys``/``src_vals`` hold the sorted runs back to back in a

@@ -116,8 +116,8 @@ def _multisplit_kv_kernel(keys_ref, vals_ref, sorted_ref, vout_ref, digit_ref,
 @functools.partial(jax.jit, static_argnames=("shift", "width", "key_bits",
                                              "val_bits", "interpret"))
 def tile_multisplit_kv(keys: jnp.ndarray, vals: jnp.ndarray, shift: int,
-                       width: int, key_bits: int, val_bits: int,
-                       interpret: bool = True):
+                       width: int, key_bits: int, val_bits: int, *,
+                       interpret: bool):
     """(T, KPB) keys + values -> digit-major (keys, values, digits, ranks,
     histograms) — the pairs path of the scatter (paper §4.6)."""
     t, kpb = keys.shape
@@ -145,7 +145,7 @@ def tile_multisplit_kv(keys: jnp.ndarray, vals: jnp.ndarray, shift: int,
 @functools.partial(jax.jit, static_argnames=("shift", "width", "key_bits",
                                              "interpret"))
 def tile_multisplit(keys: jnp.ndarray, shift: int, width: int,
-                    key_bits: int, interpret: bool = True):
+                    key_bits: int, *, interpret: bool):
     """(T, KPB) keys -> (digit-major keys, digits, in-run ranks, histograms).
 
     After this kernel the HBM scatter is r contiguous run-copies per tile

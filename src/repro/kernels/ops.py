@@ -15,8 +15,9 @@ the fused pass:
                                 doctest; the fused engine only needs it via
                                 ``fused.initial_histogram``).
 
-On this CPU container the kernels run in interpret mode; on real hardware the
-same code lowers to Mosaic.
+Every wrapper takes ``interpret`` explicitly: the sort entry points resolve it
+once (``core.ranks.resolve_interpret``) — Mosaic on a TPU, the Pallas
+interpreter elsewhere.
 """
 from __future__ import annotations
 
@@ -32,7 +33,7 @@ from repro.kernels.bitonic import bitonic_sort_rows, bitonic_sort_rows_stable
 def apply_run_copies(src: jnp.ndarray, dst: jnp.ndarray, tree):
     """Apply (src, dst) run-copy pairs to a pytree of per-key arrays.
 
-    The single idiom for consuming ``segmented_local_sort`` output: invalid
+    The single idiom for consuming the local sort's run copies: invalid
     lanes carry ``src == n``/``dst == n`` (clipped on gather, dropped on
     scatter), and untouched slots keep their old contents — done buckets
     persist in place for free.
@@ -44,7 +45,7 @@ def apply_run_copies(src: jnp.ndarray, dst: jnp.ndarray, tree):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def kernel_local_sort(keys: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+def kernel_local_sort(keys: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """Local sort of (S, L) padded buckets via the bitonic kernel."""
     return bitonic_sort_rows(keys, interpret=interpret)
 
@@ -73,60 +74,73 @@ def local_sort_class_plan(n: int, row_len: int, s_max: int,
     return tuple(classes)
 
 
-@functools.partial(jax.jit, static_argnames=("row_len", "interpret",
-                                             "classes"))
-def segmented_local_sort(keys: jnp.ndarray, seg_start: jnp.ndarray,
-                         seg_size: jnp.ndarray, seg_sortable: jnp.ndarray,
-                         row_len: int, interpret: bool = True,
-                         classes=None):
+def _class_run_copies(keys, seg_start, seg_size, seg_sortable, l: int,
+                      rows: int, prev_l: int, interpret: bool):
+    """(src, dst) run copies sorting the flagged segments of one size class:
+    sizes in (prev_l, l], gathered into ``rows`` sentinel-padded rows."""
+    n = keys.shape[0]
+    s = seg_start.shape[0]
+    sentinel = ~jnp.zeros((), keys.dtype)
+    in_cls = seg_sortable & (seg_size <= l) & (seg_size > prev_l)
+    rsel = jnp.nonzero(in_cls, size=min(rows, s), fill_value=s)[0]
+    sel = jnp.clip(rsel, 0, s - 1)
+    valid = rsel < s
+    starts_c = jnp.where(valid, seg_start[sel], n)
+    sizes_c = jnp.where(valid, seg_size[sel], 0)
+
+    lane = jnp.arange(l, dtype=jnp.int32)
+    gidx = starts_c[:, None] + lane[None, :]                  # (rows, L)
+    lv = lane[None, :] < sizes_c[:, None]
+    safe = jnp.clip(gidx, 0, max(n - 1, 0))
+    row_keys = jnp.where(lv, keys[safe], sentinel)
+    idx = jnp.where(lv, gidx, n).astype(jnp.int32)
+
+    _, si = bitonic_sort_rows_stable(row_keys, idx, interpret=interpret)
+
+    # valid lanes form each row's prefix both before and after the sort
+    dst = jnp.where(lv, gidx, n)
+    return si.reshape(-1), dst.reshape(-1)
+
+
+def _class_plan(row_len: int, s: int, classes):
+    """(L, rows, prev_L) per class; class 0 catches every size <= its L."""
+    if classes is None:
+        classes = ((row_len, s),)
+    prev = [-1] + [l for l, _ in classes[:-1]]
+    return [(l, rows, p) for (l, rows), p in zip(classes, prev)]
+
+
+def segmented_local_sort(tree, seg_start: jnp.ndarray, seg_size: jnp.ndarray,
+                         seg_sortable: jnp.ndarray, row_len: int, *,
+                         interpret: bool, classes=None):
     """Finish flagged buckets in one read+write via the stable bitonic kernel.
 
-    Gathers each flagged segment into a sentinel-padded row, sorts rows by
-    (key, global index) — so pads (index n) lose every tie and the order is
-    stable — and returns (src, dst) run copies that place the sorted prefix
-    back over the segment.  Unflagged segments are untouched (their lanes
-    return ``dst == n``).
+    ``tree`` is a pytree of per-key arrays whose first leaf is the key
+    array.  Gathers each flagged segment into a sentinel-padded row, sorts
+    rows by (key, global index) — so pads (index n) lose every tie and the
+    order is stable — and run-copies the sorted prefix back over the
+    segment, carrying every leaf.  Unflagged segments are untouched.
 
     ``classes`` is an optional size-class plan (``local_sort_class_plan``):
     segments are binned into power-of-two row widths — one fixed-shape
     bitonic launch per class — so a 3-key bucket sorts in a ``min_len`` row
     instead of a ``row_len`` one.  ``None`` keeps the single worst-case
     table: one class of width ``row_len`` with a row per segment slot.
+    Each class's copies are applied before the next class gathers, so only
+    one class table (about 2n lanes at the widest bound) is live at a time
+    — what lets a 2^26-key sort fit one chip.  Classes cover disjoint
+    segments, so the order of application does not matter.
     """
-    n = keys.shape[0]
-    s = seg_start.shape[0]
-    if classes is None:
-        classes = ((row_len, s),)
-    sentinel = ~jnp.zeros((), keys.dtype)
-    srcs, dsts = [], []
-    prev_l = -1                    # class 0 catches every size <= its width
-    for l, rows in classes:
-        in_cls = seg_sortable & (seg_size <= l) & (seg_size > prev_l)
-        rsel = jnp.nonzero(in_cls, size=min(rows, s), fill_value=s)[0]
-        sel = jnp.clip(rsel, 0, s - 1)
-        valid = rsel < s
-        starts_c = jnp.where(valid, seg_start[sel], n)
-        sizes_c = jnp.where(valid, seg_size[sel], 0)
-
-        lane = jnp.arange(l, dtype=jnp.int32)
-        gidx = starts_c[:, None] + lane[None, :]              # (rows, L)
-        lv = lane[None, :] < sizes_c[:, None]
-        safe = jnp.clip(gidx, 0, max(n - 1, 0))
-        row_keys = jnp.where(lv, keys[safe], sentinel)
-        idx = jnp.where(lv, gidx, n).astype(jnp.int32)
-
-        _, si = bitonic_sort_rows_stable(row_keys, idx, interpret=interpret)
-
-        # valid lanes form each row's prefix both before and after the sort
-        dst = jnp.where(lv, gidx, n)
-        srcs.append(si.reshape(-1))
-        dsts.append(dst.reshape(-1))
-        prev_l = l
-    return jnp.concatenate(srcs), jnp.concatenate(dsts)
+    for l, rows, prev in _class_plan(row_len, seg_start.shape[0], classes):
+        keys = jax.tree.leaves(tree)[0]
+        src, dst = _class_run_copies(keys, seg_start, seg_size, seg_sortable,
+                                     l, rows, prev, interpret)
+        tree = apply_run_copies(src, dst, tree)
+    return tree
 
 
 def tile_histogram_pass(keys: jnp.ndarray, shift: int, width: int,
-                        kpb: int = 8192, interpret: bool = True):
+                        kpb: int = 8192, *, interpret: bool):
     """Histogram step of a pass: (n,) keys -> ((T, r) tile hists, (r,) total).
 
     Example — count the top-byte digits of two u32 keys (the trailing
@@ -135,7 +149,8 @@ def tile_histogram_pass(keys: jnp.ndarray, shift: int, width: int,
         >>> import numpy as np, jax.numpy as jnp
         >>> from repro.kernels import tile_histogram_pass
         >>> x = jnp.asarray(np.array([0x01020304, 0xFF000000], np.uint32))
-        >>> hist, total = tile_histogram_pass(x, shift=24, width=8, kpb=8)
+        >>> hist, total = tile_histogram_pass(x, shift=24, width=8, kpb=8,
+        ...                                   interpret=True)
         >>> int(total[0x01]), int(total[0xFF]), int(total.sum())
         (1, 1, 2)
     """

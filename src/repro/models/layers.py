@@ -17,25 +17,11 @@ def dense_init(key, in_dim: int, out_dim: int, dtype, scale: float = 0.02):
 
 
 def abstract_mesh():
-    """``jax.sharding.get_abstract_mesh`` across jax versions (shim).
-
-    Newer jax exposes the trace-time mesh directly; older releases (like the
-    ``jax.shard_map``/``check_vma`` split handled in
-    ``core.distributed._shard_map``) only know the physical mesh bound by the
-    ``with mesh:`` context, reachable through ``thread_resources``.  Returns
-    ``None`` when no mesh is bound either way, so the layer helpers below
-    degrade to their off-mesh no-ops on every version.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        am = fn()
-        return am if am and am.axis_names else None
-    try:
-        from jax._src.mesh import thread_resources
-    except ImportError:                     # pragma: no cover - very old jax
-        return None
-    mesh = thread_resources.env.physical_mesh
-    return mesh if mesh.axis_names else None
+    """The trace-time mesh (``jax.sharding.get_abstract_mesh``), or ``None``
+    when no mesh is bound — the layer helpers below then degrade to their
+    off-mesh no-ops."""
+    am = jax.sharding.get_abstract_mesh()
+    return am if am and am.axis_names else None
 
 
 def dp_axes():

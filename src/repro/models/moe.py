@@ -7,9 +7,9 @@ experts are one d=7 digit, kimi-k2's 384 one d=9 digit).  The dispatch uses
 (§4.1 steps 1–3) with the capacity row playing the paper's reserved memory
 chunk (§4.4).  The underlying pass is ``core.plan.single_pass_partition``,
 the same engine-selected primitive as length bucketing and the distributed
-shard partition: one fused Pallas launch under interpret mode (or
-``engine="kernel"`` explicitly), an XLA stable sort on compiled hardware
-until the fused kernel's Mosaic lowering lands.
+shard partition: on a TPU the Mosaic-compiled fused Pallas launch (the
+dispatch is vmapped over token groups, so the pass runs once per group),
+an XLA stable sort elsewhere unless ``engine="kernel"`` is asked for.
 
 Dispatch is *grouped*: tokens are viewed as (G, T/G) with G = number of data
 shards, so every group's counting pass stays shard-local (the distributed

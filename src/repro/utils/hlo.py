@@ -240,7 +240,8 @@ def while_body_pallas_launches(jaxpr):
 
     For the fused hybrid engine this returns ``[1]``: one Pallas launch per
     counting pass (the loop body), with the prologue histogram and the local
-    sort outside the loop.
+    sort outside the loop.  Loops inside a kernel body run within its one
+    launch and are not listed.
     """
     if hasattr(jaxpr, "jaxpr"):
         jaxpr = jaxpr.jaxpr
@@ -248,7 +249,7 @@ def while_body_pallas_launches(jaxpr):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "while":
             out.append(pallas_launch_count(eqn.params["body_jaxpr"]))
-        else:
+        elif eqn.primitive.name != "pallas_call":
             for sub in _sub_jaxprs(eqn):
                 out.extend(while_body_pallas_launches(sub))
     return out

@@ -310,7 +310,7 @@ def test_hybrid_sort_compressed_keys(rng):
 
 @pytest.mark.slow
 def test_hybrid_sort_compressed_uint64(rng):
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         x = (rng.integers(0, 1 << 10, 2500).astype(np.uint64)
              << np.uint64(30)) | np.uint64(1 << 60)

@@ -213,6 +213,17 @@ def test_capacity_dispatch_drops_overflow(rng):
     assert np.asarray(cd.slot_valid)[1:].sum() == 0
 
 
+def test_vmapped_kernel_dispatch_matches_argsort(rng):
+    """The MoE layer vmaps capacity_dispatch over token groups; the fused
+    pass then runs once per group and must agree with the argsort engine."""
+    ids = jnp.asarray(rng.integers(0, 16, (3, 700)).astype(np.int32))
+    dispatch = lambda engine: jax.jit(jax.vmap(
+        lambda i: capacity_dispatch(i, 16, 50, engine=engine)))(ids)
+    got, want = dispatch("kernel"), dispatch("argsort")
+    for name, a, b in zip(got._fields, got, want):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), name
+
+
 def test_merge_sorted_and_multiway(rng):
     a = np.sort(rng.integers(0, 1000, 257).astype(np.uint32))
     b = np.sort(rng.integers(0, 1000, 511).astype(np.uint32))

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import ENGINES, SortConfig, hybrid_sort, lsd_sort, resolve_engine
+from repro.core.ranks import resolve_interpret
 from repro.utils import hlo
 from conftest import entropy_keys
 
@@ -58,7 +59,7 @@ def test_parity_keys(rng, dtype, n):
 
 @pytest.mark.slow
 def test_parity_uint64(rng):
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         x = rng.integers(0, 2**64, 3000, dtype=np.uint64)
         outs = _all_engine_outputs(x, TCFG)
@@ -172,6 +173,33 @@ def test_resolve_engine():
     assert resolve_engine("auto") == resolve_engine(None)
     with pytest.raises(ValueError):
         resolve_engine("bogosort")
+
+
+@pytest.mark.parametrize("backend,want", [("tpu", "kernel"), ("cpu", "argsort"),
+                                          ("gpu", "argsort")])
+def test_auto_engine_per_backend(backend, want):
+    """``auto`` on a TPU is the Mosaic kernel engine — nothing demotes it."""
+    assert resolve_engine("auto", backend=backend) == want
+    assert resolve_engine(None, backend=backend) == want
+
+
+def test_resolve_interpret():
+    assert resolve_interpret(None) == (jax.default_backend() != "tpu")
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+
+
+@pytest.mark.parametrize("entry", ["hybrid", "lsd"])
+def test_compiled_kernel_path_refuses_64bit_keys(rng, entry):
+    """Mosaic has no 64-bit vector integers: a uint64 key on the compiled
+    kernel path names its dtype and the engine that sorts it, before any
+    lowering starts — and never switches engine by itself."""
+    from jax import enable_x64
+    x = rng.integers(0, 2**64, 300, dtype=np.uint64)
+    with enable_x64():
+        sort = hybrid_sort if entry == "hybrid" else lsd_sort
+        with pytest.raises(TypeError, match=r"uint64.*engine='argsort'"):
+            sort(jnp.asarray(x), engine="kernel", interpret=False)
 
 
 # --------------------- HLO structure (acceptance gate) ----------------------

@@ -71,7 +71,7 @@ if HAVE_HYPOTHESIS:
     @settings(max_examples=10, deadline=None)
     @given(st.integers(0, 2**32 - 1), st.integers(0, 400), st.integers(0, 4))
     def test_fused_matches_argsort_property_uint64(seed, n, ands):
-        from jax.experimental import enable_x64
+        from jax import enable_x64
         rng = np.random.default_rng(seed)
         x = entropy_keys(rng, n, ands, dtype=np.uint64)
         with enable_x64():
@@ -101,7 +101,7 @@ def test_fused_matches_argsort_all_equal_and_sentinel(rng):
 
 
 def test_fused_matches_argsort_uint64(rng):
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     x = entropy_keys(rng, 2000, 2, dtype=np.uint64)
     with enable_x64():
         _assert_fused_matches_argsort(x, with_values=False)

@@ -96,7 +96,8 @@ def test_assigned_histogram_scalar_prefetch(rng):
 
 def test_tile_histogram_pass_total(rng):
     x = rng.integers(0, 2**32, 5000, dtype=np.uint32)
-    hist, total = tile_histogram_pass(jnp.asarray(x), 24, 8, kpb=1024)
+    hist, total = tile_histogram_pass(jnp.asarray(x), 24, 8, kpb=1024,
+                                      interpret=True)
     want = np.bincount((x >> 24) & 0xFF, minlength=256)
     assert np.array_equal(np.asarray(total), want)
 
@@ -122,12 +123,9 @@ def test_segmented_local_sort_done_flags(rng):
     starts = jnp.asarray([0, 300, 640], jnp.int32)
     sizes = jnp.asarray([300, 340, 360], jnp.int32)
     flags = jnp.asarray([True, False, True])
-    src, dst = segmented_local_sort(jnp.asarray(x), starts, sizes, flags, 512,
-                                    interpret=True)
-    s, d = np.asarray(src), np.asarray(dst)
-    out = x.copy()
-    m = d < n
-    out[d[m]] = x[np.clip(s, 0, n - 1)[m]]
+    (out,) = segmented_local_sort((jnp.asarray(x),), starts, sizes, flags,
+                                  512, interpret=True)
+    out = np.asarray(out)
     want = x.copy()
     want[:300] = np.sort(x[:300])
     want[640:] = np.sort(x[640:])
@@ -152,19 +150,14 @@ def test_segmented_local_sort_size_classes(rng):
     flags = jnp.asarray(flags_np)
     row_len = 1024
 
-    def apply(src, dst):
-        s, d = np.asarray(src), np.asarray(dst)
-        out = x.copy()
-        m = d < n
-        out[d[m]] = x[np.clip(s, 0, n - 1)[m]]
-        return out
+    def run(**kw):
+        return np.asarray(segmented_local_sort(
+            (jnp.asarray(x),), starts, sizes, flags, row_len, interpret=True,
+            **kw)[0])
 
     classes = local_sort_class_plan(n, row_len, s_max=len(sizes_np))
-    got = apply(*segmented_local_sort(jnp.asarray(x), starts, sizes, flags,
-                                      row_len, interpret=True,
-                                      classes=classes))
-    ref = apply(*segmented_local_sort(jnp.asarray(x), starts, sizes, flags,
-                                      row_len, interpret=True))
+    got = run(classes=classes)
+    ref = run()
     want = x.copy()
     for st_, sz, fl in zip(starts_np, sizes_np, flags_np):
         if fl and sz:
@@ -212,9 +205,10 @@ def _run_fused(x, bounds, n, kpb, sc, nsid, a_max, r, vals=(), batch=None):
     (ck, cv), (ak, av) = fused.make_ping_pong(jnp.asarray(x), vals, kpb)
     nk, nv, hist_next = fused.fused_counting_pass(
         ck, cv, ak, av, jnp.asarray(sc, jnp.int32), *blocks, base_excl,
-        jnp.asarray(nsid, jnp.int32), kpb=kpb, r=r, a_max=a_max, n=n,
+        jnp.asarray(nsid, jnp.int32), kpb=kpb, r=r, a_max=a_max,
         interpret=True)
-    return (np.asarray(nk)[:n], tuple(np.asarray(v)[:n] for v in nv),
+    return (np.asarray(fused.unpad(nk, n)),
+            tuple(np.asarray(fused.unpad(v, n)) for v in nv),
             np.asarray(hist_next).reshape(a_max, r))
 
 

@@ -230,7 +230,7 @@ def test_searchsorted_rank_byte_identical_to_counting_rank(rng):
     assert outs["searchsorted"] == outs["counting"]
     with pytest.raises(ValueError, match="rank"):
         kmerge.kway_merge_round(ck, cv, ck, cv, *tables, kway=4, tpb=tile,
-                                n=n, rank="bogus")
+                                n=n, interpret=True, rank="bogus")
 
 
 def _abstract_mesh(n, name):

@@ -150,34 +150,3 @@ def test_flash_attention_matches_naive(s, block, window):
     probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
     out_n = _gqa_values(probs.astype(v.dtype), v, 1)
     assert float(jnp.max(jnp.abs(out_f - out_n))) < 1e-5
-
-
-def test_abstract_mesh_shim_resolves_mesh_without_modern_api(monkeypatch):
-    """The jax-version shim for ``jax.sharding.get_abstract_mesh``: with the
-    modern accessor monkeypatched away, ``layers.abstract_mesh`` must still
-    resolve the mesh bound by the ``with mesh:`` context (thread_resources
-    fallback), and the layer helpers must degrade to their off-mesh no-ops
-    when nothing is bound — the failure mode that broke every qwen3_moe /
-    kimi_k2 smoke, decode-parity and MoE dispatch test on older jax."""
-    from repro.models import layers
-    from jax.sharding import PartitionSpec as P
-
-    monkeypatch.delattr(jax.sharding, "get_abstract_mesh", raising=False)
-
-    # off-mesh: no mesh resolved, dp_axes empty, constrain is the identity
-    assert layers.abstract_mesh() is None
-    assert layers.dp_axes() == ()
-    x = jnp.zeros((4, 4))
-    assert layers.constrain(x, P("data", None)) is x
-
-    # bound mesh: resolved through the thread_resources fallback
-    devs = np.array(jax.devices()[:1])
-    with jax.sharding.Mesh(devs, ("data",)):
-        am = layers.abstract_mesh()
-        assert am is not None and "data" in am.axis_names
-        assert layers.dp_axes() == ("data",)
-        # constraint over a bound axis binds; over an unbound one no-ops
-        y = layers.constrain(x, P("data", None))
-        assert y.shape == x.shape
-        assert layers.constrain(x, P("model", None)) is x
-    assert layers.dp_axes() == ()               # context exit unbinds

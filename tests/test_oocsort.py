@@ -62,7 +62,7 @@ def test_oocsort_keys_parity(rng, dtype, n):
 
 @pytest.mark.slow
 def test_oocsort_uint64(rng):
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         x = entropy_keys(rng, 5 * CHUNK + 3, 2, dtype=np.uint64)
         out = oocsort(x, CHUNK, tile=32)
@@ -291,7 +291,7 @@ def test_oocsort_spill_16x_budget_dtypes(rng, dtype):
 
 @pytest.mark.slow
 def test_oocsort_spill_uint64(rng):
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     with enable_x64():
         n = 16 * SPILL_BUDGET // 8
         x = entropy_keys(rng, n, 2, dtype=np.uint64)

@@ -1,5 +1,6 @@
 """Unit tests: HLO collective parsing, roofline math, sharding rules,
 64-bit key support, gradient compression."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -230,3 +231,40 @@ def test_compressed_psum_subprocess():
     res = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, timeout=600)
     assert "COMP-OK" in res.stdout, res.stdout + res.stderr
+
+
+@pytest.mark.parametrize("env", [None, "/elsewhere/cache"], ids=["unset", "set"])
+def test_compile_cache_dir(monkeypatch, env):
+    """Unset: the checkout's fixed, git-ignored ``.jax_cache``.  Set: JAX's
+    own variable wins and no other directory is configured."""
+    from repro.utils import compile_cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    prev = jax.config.jax_compilation_cache_dir
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env is None:
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            with open(os.path.join(root, ".gitignore")) as f:
+                assert ".jax_cache/" in f.read().split()
+        else:
+            assert got == env
+            assert jax.config.jax_compilation_cache_dir == prev
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_benchmark_runner_exits_nonzero_on_module_failure(monkeypatch):
+    """A module that raises is reported and fails the whole run."""
+    from benchmarks import run
+    from repro.utils import compile_cache
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(run, "MODULES", ["no_such_benchmark"])
+    monkeypatch.setattr(sys, "argv", ["run"])
+    with pytest.raises(SystemExit) as e:
+        run.main()
+    assert e.value.code == 1
