@@ -2,7 +2,7 @@
 # CI entry point, staged so the verify loop stays usable:
 #
 #   scripts/ci.sh fast   — fast tier-1 stage only: pytest -m "not slow"
-#                          (the sub-10-minute loop; no benchmarks).
+#                          (the sub-10-minute loop).
 #                          ZERO failures is the contract: the stage exits
 #                          non-zero on ANY failed or errored test.  The
 #                          pre-existing-failure allowance (10 known model/
@@ -11,8 +11,7 @@
 #                          any new red is a regression.
 #   scripts/ci.sh slow   — the slow-marked suites (hypothesis-heavy property
 #                          walls, large-n sweeps, multi-device subprocess
-#                          tests) + the interpret-mode benchmark smoke pass;
-#                          pairs with a separate `fast` job so CI never runs
+#                          tests); pairs with a separate `fast` job so CI never runs
 #                          the fast tier twice
 #   scripts/ci.sh faults — fault-matrix smoke only: one resilient oocsort
 #                          run per core.faults fault site with retries
@@ -22,8 +21,7 @@
 #                          dist-marked subprocess walls at 8 fake devices
 #                          (fast rung) and 16 fake devices (slow rung; the
 #                          XLA flag is exported so tests/_multidev.py widens
-#                          every wall), plus the BENCH_dist.json device-
-#                          scaling smoke
+#                          every wall)
 #   scripts/ci.sh analyze — static contract analyzer: trace every public
 #                          entry point and verify the declared launch
 #                          census, sort-free, donation, transfer-byte and
@@ -33,6 +31,9 @@
 #                          verify entry point; dist and analyze run as their
 #                          own CI jobs — analyze is repeated in full because
 #                          it is seconds-cheap)
+#
+# The chip benchmark is bench/ (python3 -m bench.run, see PERF.md); it
+# needs a TPU and is not a CI stage.
 #
 # Everything runs on a plain CPU host: the Pallas kernels execute in
 # interpret mode (the drivers default to it off-TPU), so the fused-engine
@@ -94,16 +95,6 @@ if [[ "$STAGE" == "dist" ]]; then
   echo "=== dist stage: multi-device walls at 16 devices (slow rung) ==="
   XLA_FLAGS="--xla_force_host_platform_device_count=16" \
     run_stage -m "dist and slow" "$@"
-  echo "=== dist stage: device-scaling bench smoke (BENCH_dist.json) ==="
-  python -m benchmarks.dist --smoke
-  python - <<'EOF'
-import json
-rows = json.load(open("BENCH_dist.json"))
-for note in rows.get("notes", []):
-    print("WARNING [BENCH_dist.json]:", note)
-print("BENCH_dist.json rows:",
-      sum(1 for k in rows if k not in ("notes", "ratio_convention")))
-EOF
   exit 0
 fi
 
@@ -125,21 +116,5 @@ if [[ "$STAGE" == "full" ]]; then
   python scripts/fault_matrix.py
 fi
 
-# smoke benches run BEFORE the slow suite so the BENCH artifacts exist even
-# when a slow test fails (the upload step runs if: always())
-echo "=== benchmark smoke (interpret mode, engine + entropy + ooc + spill + faults) ==="
-python -m benchmarks.run --json BENCH_smoke.json --smoke --entropy --ooc --spill --faults
-
 echo "=== tier-1 tests (slow stage: -m slow) ==="
 run_stage -m "slow" "$@"
-
-echo "=== smoke bench notes ==="
-python - <<'EOF'
-import json
-for path in ("BENCH_smoke.json", "BENCH_ooc.json"):
-    rows = json.load(open(path))
-    for note in rows.get("notes", []):
-        print(f"WARNING [{path}]:", note)
-    print(f"{path} rows:", sum(1 for k in rows if k != "notes"))
-EOF
-

@@ -43,9 +43,9 @@ def check_census(jaxpr, sites: List[PallasSite], decl: Dict,
 
     if "fused_grid" in decl:
         want_grid = int(expr.evaluate(decl["fused_grid"], params))
-        fused = [s for s in sites if s.name == "_fused_pass_kernel"]
+        fused = [s for s in sites if s.name == "fused_counting_pass"]
         if not fused:
-            findings.append("fused_grid declared but no _fused_pass_kernel "
+            findings.append("fused_grid declared but no fused_counting_pass "
                             "site in trace")
         for s in fused:
             if s.grid != (want_grid,):

@@ -55,8 +55,9 @@ class RefEvent:
 @dataclass
 class PallasSite:
     """One ``pallas_call`` equation with its analysis-relevant structure."""
-    name: str                    # kernel function name
-    src: str                     # "<kernel> at <file>:<line>"
+    name: str                    # the pallas_call's name= (a kernel function
+                                 # name where it has none)
+    src: str                     # "<name> at <kernel file>:<line>"
     grid: Tuple[int, ...]
     in_while: bool               # inside any while-loop body
     num_scalars: int             # scalar-prefetch operands (index space head)

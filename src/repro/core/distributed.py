@@ -143,6 +143,7 @@ def _dest_shards(sorted_ukeys, splitters, nshards: int, my):
     return lo + (tie_rank + my) % spread
 
 
+@jax.named_scope("exchange")
 def _exchange(sorted_ukeys, leaves, dest_shard, nshards: int, capacity: int,
               sentinel, axis_name: str, engine=None, interpret=None):
     """Partition by destination shard (one counting pass, §4.1), pad to the
@@ -152,6 +153,7 @@ def _exchange(sorted_ukeys, leaves, dest_shard, nshards: int, capacity: int,
     The shard partition routes through the same engine-selected
     ``counting_partition`` as MoE dispatch and length bucketing (core.plan),
     so the one-launch-per-counting-pass census extends to the exchange.
+    Its ops sit in the named scope ``exchange``.
     """
     part = counting_partition(dest_shard, nshards, engine=engine,
                               interpret=interpret)

@@ -116,15 +116,18 @@ def _counting_pass_jnp(state, *, k, d, lo, a_max, nd, cfg, engine, adaptive):
     n = ukeys.shape[0]
     r = 1 << d
     active = ~done
-    asegs = plan.active_segments(seg_id, done, a_max)
+    with jax.named_scope("pass_bookkeeping"):
+        asegs = plan.active_segments(seg_id, done, a_max)
     asid = asegs.index
 
-    digit = plan.digit_at(ukeys, p, k, d, lo=lo)
-    # (a, digit) histogram — only active keys contribute (M2 of the model)
-    idx = jnp.where(active, asid * r + digit, 0)
-    hist = jnp.zeros((a_max * r,), jnp.int32).at[idx].add(
-        active.astype(jnp.int32)).reshape(a_max, r)
+    with jax.named_scope("counting_pass"):
+        digit = plan.digit_at(ukeys, p, k, d, lo=lo)
+        # (a, digit) histogram — only active keys contribute (M2 of the model)
+        idx = jnp.where(active, asid * r + digit, 0)
+        hist = jnp.zeros((a_max * r,), jnp.int32).at[idx].add(
+            active.astype(jnp.int32)).reshape(a_max, r)
 
+    @jax.named_scope("counting_pass")
     def partition():
         # destination permutation: stable partition by (active segment,
         # digit); done keys carry a +inf-like composite and stay in place.
@@ -142,7 +145,8 @@ def _counting_pass_jnp(state, *, k, d, lo, a_max, nd, cfg, engine, adaptive):
         return new_keys, new_vals
 
     if adaptive:
-        skip = _skip_predicate(hist, nxt_valid, p, nd)
+        with jax.named_scope("pass_bookkeeping"):
+            skip = _skip_predicate(hist, nxt_valid, p, nd)
         new_keys, new_vals = lax.cond(skip, lambda: (ukeys, vals), partition)
         nvalid = (~skip) & (p + 2 < nd)
         p_exec = p_exec + (~skip).astype(jnp.int32)
@@ -153,12 +157,13 @@ def _counting_pass_jnp(state, *, k, d, lo, a_max, nd, cfg, engine, adaptive):
         p_exec = p_exec + 1
 
     # bucket bookkeeping: merged-group starts (R3) become the new boundaries
-    gstart, gdone = plan.merge_rows(hist, cfg.local_threshold,
-                                    cfg.merge_threshold)
-    excl = jnp.cumsum(hist, axis=1) - hist
-    dest_base = asegs.base[:, None] + excl                    # (a_max, r)
-    new_seg, new_done = plan.apply_pass_bookkeeping(
-        seg_id, done, asegs, hist, gstart, gdone, dest_base)
+    with jax.named_scope("pass_bookkeeping"):
+        gstart, gdone = plan.merge_rows(hist, cfg.local_threshold,
+                                        cfg.merge_threshold)
+        excl = jnp.cumsum(hist, axis=1) - hist
+        dest_base = asegs.base[:, None] + excl                # (a_max, r)
+        new_seg, new_done = plan.apply_pass_bookkeeping(
+            seg_id, done, asegs, hist, gstart, gdone, dest_base)
     return (new_keys, new_vals, new_seg, new_done, nvalid, p + 1, p_exec,
             n_eld)
 
@@ -182,29 +187,34 @@ def _counting_pass_fused(state, *, k, d, lo, a_max, g_max, n, nd, cfg,
     (ck, cv, ak, av, seg_id, done, hist_cur, hist_nxt, nxt_valid, p, p_exec,
      n_eld) = state
     r = 1 << d
-    asegs = plan.active_segments(seg_id, done, a_max)
-    gstart, gdone = plan.merge_rows(hist_cur, cfg.local_threshold,
-                                    cfg.merge_threshold)
-    excl = jnp.cumsum(hist_cur, axis=1) - hist_cur
-    dest_base = asegs.base[:, None] + excl                    # (a_max, r)
-    nsid = plan.next_active_table(hist_cur, cfg.local_threshold, a_max)
-    new_seg, new_done = plan.apply_pass_bookkeeping(
-        seg_id, done, asegs, hist_cur, gstart, gdone, dest_base)
+    with jax.named_scope("pass_bookkeeping"):
+        asegs = plan.active_segments(seg_id, done, a_max)
+        gstart, gdone = plan.merge_rows(hist_cur, cfg.local_threshold,
+                                        cfg.merge_threshold)
+        excl = jnp.cumsum(hist_cur, axis=1) - hist_cur
+        dest_base = asegs.base[:, None] + excl                # (a_max, r)
+        nsid = plan.next_active_table(hist_cur, cfg.local_threshold, a_max)
+        new_seg, new_done = plan.apply_pass_bookkeeping(
+            seg_id, done, asegs, hist_cur, gstart, gdone, dest_base)
 
     def launch():
-        blocks = plan.make_region_blocks(asegs.base, asegs.size, n, cfg.kpb,
-                                         g_max, batch=cfg.step_batch)
-        sc = plan.digit_window(p, k, d, lo=lo)
+        with jax.named_scope("pass_bookkeeping"):
+            blocks = plan.make_region_blocks(asegs.base, asegs.size, n,
+                                             cfg.kpb, g_max,
+                                             batch=cfg.step_batch)
+            sc = plan.digit_window(p, k, d, lo=lo)
+        pass_args = (ck, cv, ak, av, sc, *blocks, dest_base, nsid)
         if adaptive:
-            nk, nv, h1, h2 = fused.fused_counting_pass(
-                ck, cv, ak, av, sc, *blocks, dest_base, nsid,
-                kpb=cfg.kpb, r=r, a_max=a_max, interpret=interpret,
-                lookahead=True)
+            with jax.named_scope("counting_pass"):
+                nk, nv, h1, h2 = fused.fused_counting_pass(
+                    *pass_args, kpb=cfg.kpb, r=r, a_max=a_max,
+                    interpret=interpret, lookahead=True)
             return (nk, nv, ck, cv, h1.reshape(a_max, r),
                     h2.reshape(a_max, r), p + 2 < nd, p_exec + 1, n_eld)
-        nk, nv, h1 = fused.fused_counting_pass(
-            ck, cv, ak, av, sc, *blocks, dest_base, nsid,
-            kpb=cfg.kpb, r=r, a_max=a_max, interpret=interpret)
+        with jax.named_scope("counting_pass"):
+            nk, nv, h1 = fused.fused_counting_pass(
+                *pass_args, kpb=cfg.kpb, r=r, a_max=a_max,
+                interpret=interpret)
         return (nk, nv, ck, cv, h1.reshape(a_max, r), hist_nxt, nxt_valid,
                 p_exec + 1, n_eld)
 
@@ -214,7 +224,8 @@ def _counting_pass_fused(state, *, k, d, lo, a_max, g_max, n, nd, cfg,
             # lookahead histogram becomes the next pass's current histogram
             return (ck, cv, ak, av, hist_nxt, jnp.zeros_like(hist_nxt),
                     jnp.bool_(False), p_exec, n_eld + 1)
-        skip = _skip_predicate(hist_cur, nxt_valid, p, nd)
+        with jax.named_scope("pass_bookkeeping"):
+            skip = _skip_predicate(hist_cur, nxt_valid, p, nd)
         nk, nv, nak, nav, h_cur, h_nxt, nvalid, npe, nne = lax.cond(
             skip, elide, launch)
     else:
@@ -225,6 +236,7 @@ def _counting_pass_fused(state, *, k, d, lo, a_max, g_max, n, nd, cfg,
             p + 1, npe, nne)
 
 
+@jax.named_scope("local_sort")
 def _local_sort(ukeys, vals, seg_id, done):
     """Finish done buckets in one read+write: sort by (bucket, remaining key).
 
@@ -253,14 +265,18 @@ def _local_sort_kernel(ukeys, vals, seg_id, done, *, s_max, row_len, classes,
     matches the jnp engines' stable lexsort exactly.
     """
     n = ukeys.shape[0]
-    boundary = jnp.concatenate([jnp.ones((1,), bool),
-                                seg_id[1:] != seg_id[:-1]])
-    starts = jnp.nonzero(boundary, size=s_max, fill_value=n)[0].astype(jnp.int32)
-    ends = jnp.concatenate([starts[1:], jnp.array([n], jnp.int32)])
-    sizes = ends - starts                                     # 0 on padding rows
-    sortable = done[jnp.clip(starts, 0, n - 1)] & (starts < n)
-    return segmented_local_sort((ukeys, vals), starts, sizes, sortable,
-                                row_len, interpret=interpret, classes=classes)
+    with jax.named_scope("local_sort"):
+        with jax.named_scope("bounds"):
+            boundary = jnp.concatenate([jnp.ones((1,), bool),
+                                        seg_id[1:] != seg_id[:-1]])
+            starts = jnp.nonzero(boundary, size=s_max,
+                                 fill_value=n)[0].astype(jnp.int32)
+            ends = jnp.concatenate([starts[1:], jnp.array([n], jnp.int32)])
+            sizes = ends - starts                         # 0 on padding rows
+            sortable = done[jnp.clip(starts, 0, n - 1)] & (starts < n)
+        return segmented_local_sort((ukeys, vals), starts, sizes, sortable,
+                                    row_len, interpret=interpret,
+                                    classes=classes)
 
 
 def _local_row_len(n: int, cfg: model.SortConfig) -> int:
@@ -279,9 +295,23 @@ def local_sort_classes(n: int, cfg: model.SortConfig):
                                  model.max_total_buckets(n, cfg))
 
 
+@functools.lru_cache(maxsize=64)
+def local_sort_lanes(n: int, cfg: model.SortConfig) -> int:
+    """Lanes the kernel engine's local sort gathers into its class tables:
+    Σ rows × L over ``local_sort_classes(n, cfg)``, fixed by the plan."""
+    return sum(l * rows for l, rows in local_sort_classes(n, cfg))
+
+
+def _planned_passes(k: int, lo: int, d: int, max_passes: Optional[int]):
+    """Counting passes the schedule plans over the live window [lo, k)."""
+    nd = model.num_digits(max(k - lo, 0), d)
+    return nd if max_passes is None else min(nd, max_passes)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "k", "return_stats",
                                              "max_passes", "engine",
                                              "interpret", "lo", "adaptive"))
+@jax.named_scope("hybrid_sort")
 def _hybrid_sort_bits(ukeys, vals, cfg: model.SortConfig, k: int,
                       return_stats: bool, max_passes: Optional[int],
                       engine: str, interpret: bool,
@@ -289,9 +319,7 @@ def _hybrid_sort_bits(ukeys, vals, cfg: model.SortConfig, k: int,
     n = ukeys.shape[0]
     d = cfg.d
     r = 1 << d
-    nd = model.num_digits(max(k - lo, 0), d)   # passes over the live window
-    if max_passes is not None:
-        nd = min(nd, max_passes)
+    nd = _planned_passes(k, lo, d, max_passes)
     a_max = model.max_active_buckets(n, cfg)
 
     done0 = jnp.full((n,), n <= cfg.local_threshold)
@@ -302,11 +330,13 @@ def _hybrid_sort_bits(ukeys, vals, cfg: model.SortConfig, k: int,
     if engine == "kernel":
         g_max = plan.max_region_blocks(n, cfg.kpb, a_max)
         leaves, treedef = jax.tree.flatten(vals)
-        (ck, cv), (ak, av) = fused.make_ping_pong(ukeys, leaves, cfg.kpb)
+        with jax.named_scope("ping_pong"):
+            (ck, cv), (ak, av) = fused.make_ping_pong(ukeys, leaves, cfg.kpb)
         # the one unfused sweep of the sort: pass 0's histogram (§4.3)
         w0 = min(d, max(k - lo, 1))
-        seg_hist0 = fused.initial_histogram(ck, n, max(k - w0, 0), w0, r,
-                                            a_max, interpret=interpret)
+        with jax.named_scope("prologue_histogram"):
+            seg_hist0 = fused.initial_histogram(ck, n, max(k - w0, 0), w0, r,
+                                                a_max, interpret=interpret)
 
         def cond(state):
             done, p = state[5], state[9]
@@ -321,8 +351,10 @@ def _hybrid_sort_bits(ukeys, vals, cfg: model.SortConfig, k: int,
                            (ck, cv, ak, av, seg0, done0, seg_hist0,
                             jnp.zeros_like(seg_hist0), nxt_valid0,
                             z, z, z))
-        ukeys = fused.unpad(ck, n, ukeys.dtype)
-        vals = jax.tree.unflatten(treedef, [fused.unpad(v, n) for v in cv])
+        with jax.named_scope("unpad"):
+            ukeys = fused.unpad(ck, n, ukeys.dtype)
+            vals = jax.tree.unflatten(treedef,
+                                      [fused.unpad(v, n) for v in cv])
     else:
         def cond(state):
             done, p = state[3], state[5]
@@ -388,55 +420,83 @@ def hybrid_sort(keys: jnp.ndarray, values: Any = None,
 
     Returns ``sorted_keys``, or ``(sorted_keys, permuted_values)`` if values
     were given; append ``stats`` when ``return_stats``.
+
+    Under ``jax.profiler`` the call is a host span ``hybrid_sort`` whose
+    arguments are the plan's counters (``n``, ``key_bits``, ``engine``, the
+    live window ``lo``/``hi``, ``planned_passes`` and, on the kernel engine,
+    ``local_sort_lanes``), with the children ``hybrid_sort.prologue``
+    (holding ``hybrid_sort.live_bit_window``, the host copy and bit
+    reduce) and ``hybrid_sort.dispatch``.  Inside the program every stage
+    sits in a named scope under ``hybrid_sort`` (``ping_pong``,
+    ``prologue_histogram``, ``pass_bookkeeping``, ``counting_pass``,
+    ``local_sort/{bounds,rows,bitonic,copy_back}``, ``unpad``), which the
+    device trace carries in each op's ``op_name``.
     """
     if keys.ndim != 1:
         raise ValueError("hybrid_sort expects a 1-D key array")
-    interpret = resolve_interpret(interpret)
-    k = bijection.key_bits(keys.dtype)
-    if k > 32 and not jax.config.jax_enable_x64:
-        raise RuntimeError("64-bit keys require jax_enable_x64")
-    cfg = cfg or model.default_config(k // 8)
-    if adaptive is None:
-        adaptive = cfg.adaptive
-    # explicit argument > cfg.rank_engine > backend default
-    engine = resolve_engine(engine if engine is not None else cfg.rank_engine)
-    n = keys.shape[0]
-    if n == 0:
-        out = (keys, values) if values is not None else keys
-        if return_stats:
-            z = jnp.int32(0)
-            return (*((out,) if values is None else out),
-                    SortStats(z, jnp.bool_(False), z, z, z))
-        return out
+    with jax.profiler.TraceAnnotation("hybrid_sort") as span:
+        with jax.profiler.TraceAnnotation("hybrid_sort.prologue"):
+            interpret = resolve_interpret(interpret)
+            k = bijection.key_bits(keys.dtype)
+            if k > 32 and not jax.config.jax_enable_x64:
+                raise RuntimeError("64-bit keys require jax_enable_x64")
+            cfg = cfg or model.default_config(k // 8)
+            if adaptive is None:
+                adaptive = cfg.adaptive
+            # explicit argument > cfg.rank_engine > backend default
+            engine = resolve_engine(engine if engine is not None
+                                    else cfg.rank_engine)
+            n = keys.shape[0]
+            if n == 0:
+                out = (keys, values) if values is not None else keys
+                if return_stats:
+                    z = jnp.int32(0)
+                    return (*((out,) if values is None else out),
+                            SortStats(z, jnp.bool_(False), z, z, z))
+                return out
 
-    concrete = not isinstance(keys, jax.core.Tracer)
-    cplan = None
-    lo, hi = 0, k
-    if compress:
-        if not concrete:
-            raise ValueError("compress=True requires concrete (non-traced) "
-                             "keys: the packing plan is data-dependent")
-        cplan = bijection.compression_plan_np(
-            bijection.to_ordered_bits_np(np.asarray(keys)))
-        ukeys = bijection.pack_ordered_bits(bijection.to_ordered_bits(keys),
-                                            cplan)
-        # every packed column is live by construction; sort just those bits
-        lo, hi = 0, cplan.packed_bits
-    else:
-        ukeys = bijection.to_ordered_bits(keys)
-        if adaptive and concrete:
-            lo, hi = live_bit_window(bijection.to_ordered_bits_np(
-                np.asarray(keys)))
+            concrete = not isinstance(keys, jax.core.Tracer)
+            cplan = None
+            lo, hi = 0, k
+            if compress:
+                if not concrete:
+                    raise ValueError("compress=True requires concrete "
+                                     "(non-traced) keys: the packing plan "
+                                     "is data-dependent")
+                with jax.profiler.TraceAnnotation(
+                        "hybrid_sort.live_bit_window"):
+                    cplan = bijection.compression_plan_np(
+                        bijection.to_ordered_bits_np(np.asarray(keys)))
+                ukeys = bijection.pack_ordered_bits(
+                    bijection.to_ordered_bits(keys), cplan)
+                # every packed column is live by construction; sort just
+                # those bits
+                lo, hi = 0, cplan.packed_bits
+            else:
+                ukeys = bijection.to_ordered_bits(keys)
+                if adaptive and concrete:
+                    with jax.profiler.TraceAnnotation(
+                            "hybrid_sort.live_bit_window"):
+                        lo, hi = live_bit_window(bijection.to_ordered_bits_np(
+                            np.asarray(keys)))
 
-    if engine == "kernel":
-        fused.require_kernel_keys(ukeys.dtype, keys.dtype, interpret)
-    vals = values if values is not None else ()
-    ukeys, vals, stats = _hybrid_sort_bits(ukeys, vals, cfg, hi, return_stats,
-                                           max_passes, engine, interpret,
-                                           lo=lo, adaptive=adaptive)
-    if cplan is not None:
-        ukeys = bijection.unpack_ordered_bits(ukeys, cplan)
-    out_keys = bijection.from_ordered_bits(ukeys, keys.dtype)
+            if engine == "kernel":
+                fused.require_kernel_keys(ukeys.dtype, keys.dtype, interpret)
+            vals = values if values is not None else ()
+        if span.is_enabled():
+            counters = dict(n=n, key_bits=k, engine=engine, lo=lo, hi=hi,
+                            planned_passes=_planned_passes(hi, lo, cfg.d,
+                                                           max_passes))
+            if engine == "kernel":
+                counters["local_sort_lanes"] = local_sort_lanes(n, cfg)
+            span.set_metadata(**counters)
+        with jax.profiler.TraceAnnotation("hybrid_sort.dispatch"):
+            ukeys, vals, stats = _hybrid_sort_bits(
+                ukeys, vals, cfg, hi, return_stats, max_passes, engine,
+                interpret, lo=lo, adaptive=adaptive)
+        if cplan is not None:
+            ukeys = bijection.unpack_ordered_bits(ukeys, cplan)
+        out_keys = bijection.from_ordered_bits(ukeys, keys.dtype)
     if values is None:
         return (out_keys, stats) if return_stats else out_keys
     return (out_keys, vals, stats) if return_stats else (out_keys, vals)
@@ -455,9 +515,9 @@ ANALYSIS_CONTRACT = {
         "fused_grid": "ceil_div(g_max, B)",
     },
     "sort_free": True,
-    "donation": {"_fused_pass_kernel": "1 + vals"},
+    "donation": {"fused_counting_pass": "1 + vals"},
     "transfer": {
-        "sweep_kernels": ["_hist_kernel", "_fused_pass_kernel"],
+        "sweep_kernels": ["radix_histogram_total", "fused_counting_pass"],
         "bytes": "(2 * passes + 1) * n_pad * kb + 2 * passes * n_pad * vb",
     },
 }

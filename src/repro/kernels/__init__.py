@@ -22,8 +22,8 @@ ops         — local-sort / histogram drivers (the fused counting passes moved
               to ``fused``; the per-bucket multi-launch drivers are retired)
 ref         — pure-jnp oracles
 
-Memory-transfer accounting (paper §4.3–§4.4, the roofline target for
-BENCH_hybrid.json): one *unfused* counting pass over n keys of b bytes moves
+Memory-transfer accounting (paper §4.3–§4.4, the roofline target that
+``bench/roofline.py`` measures the counting passes against): one *unfused* counting pass over n keys of b bytes moves
 ``2R + 1W`` key sweeps (histogram read + scatter read + scatter write) =
 3·n·b bytes; values add ``1R + 1W`` = 2·n·v.  The fused pass moves
 ``1R + 1W`` = 2·n·b (+ 2·n·v) because pass i+1's histogram is computed while
@@ -54,10 +54,11 @@ with equality on full-entropy keys (zero overhead: the skip predicate reads
 the histogram the fused pass already produced) and p_exec → 1 on clustered
 / shared-prefix keys.  Executed-vs-nominal counts are census-gated (one
 ``pallas_call`` per *executed* pass — elided passes launch nothing;
-tests/test_adaptive.py) and reported per entropy rung by the
-``entropy/...`` rows of BENCH_hybrid.json and ``SortStats.elided_passes``.
+tests/test_adaptive.py) and reported per call by ``SortStats`` (the chip
+benchmark, ``bench/``, checks its traced pass launches against
+``SortStats.counting_passes``).
 
-Out-of-core transfer accounting (§5, the BENCH_ooc.json roofline row): for
+Out-of-core transfer accounting (§5; no chip benchmark cell yet): for
 N keys in C = ⌈N/chunk⌉ device-sized chunks merged K ways per round, per
 key of b bytes (values: v bytes):
 
@@ -92,18 +93,16 @@ host-side for free, and device bytes stay bounded by the budget
 is what makes the §5 beyond-device-memory claim literal.  The merge-path
 diagonal searches add O(tiles · K · log chunk) gathered (host-spill:
 probed) elements and O(G·K) int32 descriptor uploads per strip, sub-leading
-for any real tile size.  On this CPU container interpret-mode overhead
-dominates, so the tracked proxy is the argsort/ooc ratio trajectory in
-BENCH_ooc.json (``spill/...`` rows for the streamed regime) plus the
-structural census (``utils.hlo.launch_census``).
+for any real tile size.  Until a chip cell measures it, the tracked proxy
+is the structural census (``utils.hlo.launch_census``).
 [verified-by: contracts ``ooc_chunk_sort`` / ``ooc_merge_round`` /
 ``ooc_slab_sweep`` — ``transfer.hbm_bytes`` pins the 2·(b+v) device sweep
 per merge/slab row, ``census`` the one-launch-per-round gate, and the
 ``descriptor_tables`` report proves the merge-path/spill tables write
 disjoint, exactly-covering output ranges]
 
-Distributed-exchange accounting (``core.distributed``, the BENCH_dist.json
-device-scaling row): for n_local keys of b bytes (+ v payload bytes) per
+Distributed-exchange accounting (``core.distributed``; the chip benchmark's
+``kv32_uniform.dist4`` cell times the ``exchange`` scope): for n_local keys of b bytes (+ v payload bytes) per
 shard over P shards, per *executed* exchange attempt (attempts ledgered in
 ``DistStats.exchange_attempts``; re-samples replay every row below):
 
@@ -148,8 +147,7 @@ so its costs are ledgered separately and the clean formulas stay exact.
     overhead is exactly ``retry_link_bytes == Σ_s F_s · p_s``.
   * Checksums — ``host_checksum`` runs host-side over buffers already
     resident there: 0 extra link bytes, one O(run) host sweep per crossing
-    (fault-free overhead is pure host CPU, gated ≤ 1.15x by the
-    ``faults/...`` rows of BENCH_ooc.json).
+    (fault-free overhead is pure host CPU).
   * Checkpoints — round-granular checkpoints publish *host-resident* runs
     to disk: 0 extra link bytes (``rounds_checkpointed`` rounds pay
     ≈ Σ run bytes + manifest to the store, not to the device link).

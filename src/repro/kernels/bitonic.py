@@ -129,8 +129,9 @@ def _rows_kernel(*refs, less, width: int, interpret: bool):
 _STEP_ELEMS = 8192
 
 
-def _rows_call(less, arrs, interpret: bool):
-    """Sort each row of equally shaped (S, L) operands with one launch."""
+def _rows_call(less, arrs, interpret: bool, name: str):
+    """Sort each row of equally shaped (S, L) operands with one launch,
+    named ``name`` in the program (and in a profile)."""
     s, l = arrs[0].shape
     assert l & (l - 1) == 0, "bitonic needs power-of-two rows"
     step = max(l, _STEP_ELEMS)
@@ -150,6 +151,7 @@ def _rows_call(less, arrs, interpret: bool):
         out_specs=[spec] * len(flat),
         out_shape=[jax.ShapeDtypeStruct(f.shape, f.dtype) for f in flat],
         interpret=interpret,
+        name=name,
     )(*flat)
     return tuple(o.reshape(-1)[:s * l].reshape(s, l) for o in out)
 
@@ -157,7 +159,7 @@ def _rows_call(less, arrs, interpret: bool):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bitonic_sort_rows(keys: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """Sort each row of (S, L) ascending; L must be a power of two."""
-    return _rows_call(_key_less, [keys], interpret)[0]
+    return _rows_call(_key_less, [keys], interpret, "bitonic_sort_rows")[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -170,7 +172,8 @@ def bitonic_sort_rows_stable(keys: jnp.ndarray, idx: jnp.ndarray, *,
     collisions — the segmented local-sort path of the hybrid sort's kernel
     engine relies on both properties.
     """
-    return _rows_call(_key_idx_less, [keys, idx], interpret)
+    return _rows_call(_key_idx_less, [keys, idx], interpret,
+                      "bitonic_sort_rows_stable")
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -181,4 +184,5 @@ def bitonic_sort_rows_kv(keys: jnp.ndarray, vals: jnp.ndarray, *,
     NOTE: with duplicate keys the value attribution follows the network's
     swaps, which matches the paper's non-stable pair semantics.
     """
-    return _rows_call(_key_less, [keys, vals], interpret)
+    return _rows_call(_key_less, [keys, vals], interpret,
+                      "bitonic_sort_rows_kv")

@@ -695,6 +695,7 @@ def fused_counting_pass(src_keys, src_vals, alt_keys, alt_vals, pass_scalars,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="fused_counting_pass",
     ))(pass_scalars, src_keys, *src_vals, alt_keys, *alt_vals, desc,
        segtab)
 

@@ -87,6 +87,7 @@ def radix_histogram(keys: jnp.ndarray, shift: int, width: int, *,
         out_specs=pl.BlockSpec((tb, r), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, r), jnp.int32),
         interpret=interpret,
+        name="radix_histogram",
     )(keys)
 
 
@@ -110,4 +111,5 @@ def radix_histogram_total(keys: jnp.ndarray, shift: int, width: int, *,
         out_specs=pl.BlockSpec((1, r), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, r), jnp.int32),
         interpret=interpret,
+        name="radix_histogram_total",
     )(keys)[0]

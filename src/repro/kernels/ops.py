@@ -11,7 +11,7 @@ the fused pass:
   * ``segmented_local_sort``  — finish done buckets via the stable bitonic
                                 kernel (R1: one read + one write),
   * ``kernel_local_sort``     — plain padded-row bitonic driver,
-  * ``tile_histogram_pass``   — standalone histogram sweep (benchmarks /
+  * ``tile_histogram_pass``   — standalone histogram sweep (tests /
                                 doctest; the fused engine only needs it via
                                 ``fused.initial_histogram``).
 
@@ -81,25 +81,28 @@ def _class_run_copies(keys, seg_start, seg_size, seg_sortable, l: int,
     n = keys.shape[0]
     s = seg_start.shape[0]
     sentinel = ~jnp.zeros((), keys.dtype)
-    in_cls = seg_sortable & (seg_size <= l) & (seg_size > prev_l)
-    rsel = jnp.nonzero(in_cls, size=min(rows, s), fill_value=s)[0]
-    sel = jnp.clip(rsel, 0, s - 1)
-    valid = rsel < s
-    starts_c = jnp.where(valid, seg_start[sel], n)
-    sizes_c = jnp.where(valid, seg_size[sel], 0)
+    with jax.named_scope("rows"):
+        in_cls = seg_sortable & (seg_size <= l) & (seg_size > prev_l)
+        rsel = jnp.nonzero(in_cls, size=min(rows, s), fill_value=s)[0]
+        sel = jnp.clip(rsel, 0, s - 1)
+        valid = rsel < s
+        starts_c = jnp.where(valid, seg_start[sel], n)
+        sizes_c = jnp.where(valid, seg_size[sel], 0)
 
-    lane = jnp.arange(l, dtype=jnp.int32)
-    gidx = starts_c[:, None] + lane[None, :]                  # (rows, L)
-    lv = lane[None, :] < sizes_c[:, None]
-    safe = jnp.clip(gidx, 0, max(n - 1, 0))
-    row_keys = jnp.where(lv, keys[safe], sentinel)
-    idx = jnp.where(lv, gidx, n).astype(jnp.int32)
+        lane = jnp.arange(l, dtype=jnp.int32)
+        gidx = starts_c[:, None] + lane[None, :]              # (rows, L)
+        lv = lane[None, :] < sizes_c[:, None]
+        safe = jnp.clip(gidx, 0, max(n - 1, 0))
+        row_keys = jnp.where(lv, keys[safe], sentinel)
+        idx = jnp.where(lv, gidx, n).astype(jnp.int32)
 
-    _, si = bitonic_sort_rows_stable(row_keys, idx, interpret=interpret)
+    with jax.named_scope("bitonic"):
+        _, si = bitonic_sort_rows_stable(row_keys, idx, interpret=interpret)
 
     # valid lanes form each row's prefix both before and after the sort
-    dst = jnp.where(lv, gidx, n)
-    return si.reshape(-1), dst.reshape(-1)
+    with jax.named_scope("copy_back"):
+        dst = jnp.where(lv, gidx, n)
+        return si.reshape(-1), dst.reshape(-1)
 
 
 def _class_plan(row_len: int, s: int, classes):
@@ -119,7 +122,9 @@ def segmented_local_sort(tree, seg_start: jnp.ndarray, seg_size: jnp.ndarray,
     array.  Gathers each flagged segment into a sentinel-padded row, sorts
     rows by (key, global index) — so pads (index n) lose every tie and the
     order is stable — and run-copies the sorted prefix back over the
-    segment, carrying every leaf.  Unflagged segments are untouched.
+    segment, carrying every leaf.  Unflagged segments are untouched.  The
+    stages sit in the named scopes ``rows`` (the gathers into class
+    tables), ``bitonic`` and ``copy_back`` (the run copies).
 
     ``classes`` is an optional size-class plan (``local_sort_class_plan``):
     segments are binned into power-of-two row widths — one fixed-shape
@@ -135,7 +140,8 @@ def segmented_local_sort(tree, seg_start: jnp.ndarray, seg_size: jnp.ndarray,
         keys = jax.tree.leaves(tree)[0]
         src, dst = _class_run_copies(keys, seg_start, seg_size, seg_sortable,
                                      l, rows, prev, interpret)
-        tree = apply_run_copies(src, dst, tree)
+        with jax.named_scope("copy_back"):
+            tree = apply_run_copies(src, dst, tree)
     return tree
 
 

@@ -12,8 +12,8 @@ For each cell this driver:
      2x16x16 multi-pod),
   4. prints ``memory_analysis()`` (fits?) and ``cost_analysis()`` (FLOPs,
      bytes) and parses the partitioned HLO for per-chip collective wire bytes,
-  5. writes a JSON artifact consumed by benchmarks/roofline.py and
-     EXPERIMENTS.md.
+  5. writes a JSON artifact (the chip benchmark, ``bench/``, does not
+     read it).
 
 Usage:
   python -m repro.launch.dryrun --arch internlm2_1_8b --shape train_4k --mesh pod

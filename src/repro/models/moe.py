@@ -172,9 +172,9 @@ ANALYSIS_CONTRACT = {
         "fused_grid": "ceil_div(g_max, B)",
     },
     "sort_free": True,
-    "donation": {"_fused_pass_kernel": "1 + vals"},
+    "donation": {"fused_counting_pass": "1 + vals"},
     "transfer": {
-        "sweep_kernels": ["_hist_kernel", "_fused_pass_kernel"],
+        "sweep_kernels": ["radix_histogram_total", "fused_counting_pass"],
         "bytes": "(2 * passes + 1) * n_pad * kb + 2 * passes * n_pad * vb",
     },
 }

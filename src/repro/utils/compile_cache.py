@@ -2,7 +2,7 @@
 
 JAX keys its persistent cache by program *and* directory, so a cache only
 pays if the directory is stable across runs.  Entry points that compile the
-sort at full size (``chip_smoke.py``, ``benchmarks.run``) call
+sort at full size (``chip_smoke.py``, ``bench/run.py``) call
 :func:`enable_compile_cache` once at start-up; importing ``repro`` never
 touches the cache.
 """

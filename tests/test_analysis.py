@@ -249,7 +249,7 @@ def test_ref_access_counts_on_real_fused_kernel():
     fn, args, _ = contracts.REGISTRY["single_pass_partition"].make()
     jx = jax.make_jaxpr(fn)(*args)
     fused_sites = [s for s in collect_pallas_sites(jx)
-                   if s.name == "_fused_pass_kernel"]
+                   if s.name == "fused_counting_pass"]
     assert fused_sites
     site = fused_sites[0]
     counts = ref_access_counts(site.kernel_jaxpr)

@@ -124,3 +124,43 @@ def test_kernel_engine_sort_compiles_and_fits_one_chip(one_chip):
     assert c.as_text().count("tpu_custom_call") == \
         2 + len(hybrid.local_sort_classes(n, cfg))
     _fits(c)
+
+
+# --- names a profile of the chip reads (bench/stages.py, bench/events.json)
+
+KERNELS = ["fused_counting_pass", "radix_histogram_total",
+           "bitonic_sort_rows_stable"]
+SCOPES = ["ping_pong", "prologue_histogram", "pass_bookkeeping",
+          "counting_pass", "local_sort/bounds", "local_sort/rows",
+          "local_sort/bitonic", "local_sort/copy_back", "unpad"]
+
+
+@pytest.fixture(scope="module")
+def named_program(one_chip):
+    """HLO instruction name -> op_name of the compiled kernel-engine sort of
+    2^14 key-value pairs: what a trace of the chip names and what the
+    benchmark maps back to the sort's stages."""
+    import re
+    n, cfg = 1 << 14, model.default_config(4)
+    spec = _spec(one_chip, (n,), jnp.uint32)
+    c = _compile(lambda k, v: hybrid._hybrid_sort_bits(
+        k, v, cfg, 32, False, None, "kernel", False, adaptive=True),
+        spec, spec)
+    return dict(re.findall(r'%(\S+) = [^\n]*op_name="([^"]*)"', c.as_text()))
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_kernel_instructions_keep_their_names(named_program, kernel):
+    """Each Mosaic kernel is an instruction named after its pallas_call, the
+    prefix ``bench/events.json`` finds it by, and its ``name=`` is in its
+    op_name."""
+    named = {i: o for i, o in named_program.items()
+             if i.startswith(kernel + ".")}
+    assert named and all(f"/{kernel}/pallas_call" in o
+                         for o in named.values())
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_stage_scopes_reach_the_compiled_program(named_program, scope):
+    assert any("/hybrid_sort/" in o and f"/{scope}/" in o
+               for o in named_program.values())

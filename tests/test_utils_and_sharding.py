@@ -256,15 +256,3 @@ def test_compile_cache_dir(monkeypatch, env):
             assert jax.config.jax_compilation_cache_dir == prev
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
-
-
-def test_benchmark_runner_exits_nonzero_on_module_failure(monkeypatch):
-    """A module that raises is reported and fails the whole run."""
-    from benchmarks import run
-    from repro.utils import compile_cache
-    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
-    monkeypatch.setattr(run, "MODULES", ["no_such_benchmark"])
-    monkeypatch.setattr(sys, "argv", ["run"])
-    with pytest.raises(SystemExit) as e:
-        run.main()
-    assert e.value.code == 1
