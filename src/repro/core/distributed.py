@@ -347,9 +347,10 @@ def _shard_map(fn, mesh, in_specs, out_specs):
 
 
 # --- contract declaration (verified by repro.analysis; see analysis/contracts)
-# Shard-body census: per chunk one full hybrid sort, per cond-guarded attempt
-# per chunk one bucketing counting pass (2 sites), plus the 2-bucket validity
-# compaction.  Link bytes re-derive the ICI table of kernels/__init__ from
+# Shard-body census: per chunk one full hybrid sort (its pass loop and one
+# tile loop per local-sort class, one launch site each), per cond-guarded
+# attempt per chunk one bucketing counting pass (2 sites), plus the 2-bucket
+# validity compaction.  Link bytes re-derive the ICI table of kernels/__init__ from
 # the collective-primitive result shapes: per attempt per chunk one keys +
 # ``leaves`` payload + one counts all_to_all at capacity padding, per attempt
 # one splitter-sample all_gather (samp lists the gathered per-shard sample
@@ -359,7 +360,7 @@ ANALYSIS_CONTRACT = {
     "census": {
         "launch_total": "chunks * (2 + classes)"
                         " + 2 * attempts * chunks + 2",
-        "while_body_launches": "[1] * chunks",
+        "while_body_launches": "[1] * (chunks * (1 + classes))",
     },
     "sort_free": True,
     "link": {

@@ -66,7 +66,8 @@ from repro.core import bijection, model, plan
 from repro.core.ranks import (resolve_engine, resolve_interpret,
                               stable_partition_dest)
 from repro.kernels import fused
-from repro.kernels.ops import local_sort_class_plan, segmented_local_sort
+from repro.kernels.ops import (local_sort_class_plan, local_sort_tile_count,
+                               segmented_local_sort)
 
 
 class SortStats(NamedTuple):
@@ -76,6 +77,8 @@ class SortStats(NamedTuple):
     max_segment: jnp.ndarray       # largest segment at exit
     elided_passes: jnp.ndarray = jnp.int32(0)  # adaptive: passes advanced
                                                # with no launch/partition
+    local_sort_tiles: jnp.ndarray = jnp.int32(0)  # tiles the kernel
+                                                  # engine's local sort runs
 
 
 def live_bit_window(ukeys) -> tuple:
@@ -254,26 +257,34 @@ def _local_sort(ukeys, vals, seg_id, done):
     return ukeys[perm], jax.tree.map(lambda v: v[perm], vals)
 
 
+def _done_segments(seg_id, done, s_max: int):
+    """``(starts, sizes, sortable)`` of the segments, padded to ``s_max``
+    slots (start n, size 0): which done buckets the local sort finishes."""
+    n = seg_id.shape[0]
+    boundary = jnp.concatenate([jnp.ones((1,), bool),
+                                seg_id[1:] != seg_id[:-1]])
+    starts = jnp.nonzero(boundary, size=s_max,
+                         fill_value=n)[0].astype(jnp.int32)
+    ends = jnp.concatenate([starts[1:], jnp.array([n], jnp.int32)])
+    sizes = ends - starts                                 # 0 on padding rows
+    sortable = done[jnp.clip(starts, 0, n - 1)] & (starts < n)
+    return starts, sizes, sortable
+
+
 def _local_sort_kernel(ukeys, vals, seg_id, done, *, s_max, row_len, classes,
                        interpret):
     """Kernel-engined local sort: done buckets gather into sentinel-padded
     rows binned by power-of-two size class (R1 guarantees the widest class
     is next_pow2(∂̂); §4.2's local sort configurations keep tiny buckets off
     worst-case padding), the stable bitonic kernel sorts each class's rows
-    by (key, position), and the run copies scatter the sorted prefixes back.
-    Non-done buckets at digit exhaustion hold equal keys, so skipping them
-    matches the jnp engines' stable lexsort exactly.
+    by (key, position), a tile of occupied rows at a time, and the run
+    copies scatter the sorted prefixes back.  Non-done buckets at digit
+    exhaustion hold equal keys, so skipping them matches the jnp engines'
+    stable lexsort exactly.
     """
-    n = ukeys.shape[0]
     with jax.named_scope("local_sort"):
         with jax.named_scope("bounds"):
-            boundary = jnp.concatenate([jnp.ones((1,), bool),
-                                        seg_id[1:] != seg_id[:-1]])
-            starts = jnp.nonzero(boundary, size=s_max,
-                                 fill_value=n)[0].astype(jnp.int32)
-            ends = jnp.concatenate([starts[1:], jnp.array([n], jnp.int32)])
-            sizes = ends - starts                         # 0 on padding rows
-            sortable = done[jnp.clip(starts, 0, n - 1)] & (starts < n)
+            starts, sizes, sortable = _done_segments(seg_id, done, s_max)
         return segmented_local_sort((ukeys, vals), starts, sizes, sortable,
                                     row_len, interpret=interpret,
                                     classes=classes)
@@ -297,9 +308,41 @@ def local_sort_classes(n: int, cfg: model.SortConfig):
 
 @functools.lru_cache(maxsize=64)
 def local_sort_lanes(n: int, cfg: model.SortConfig) -> int:
-    """Lanes the kernel engine's local sort gathers into its class tables:
-    Σ rows × L over ``local_sort_classes(n, cfg)``, fixed by the plan."""
+    """The plan's capacity of the kernel engine's local sort: Σ rows × L
+    over ``local_sort_classes(n, cfg)``, fixed by (n, cfg).  An upper bound:
+    the lanes a call actually runs are at most ``local_sort_tiles`` ×
+    ``ops.local_sort_tile_lanes(n, row_len)``."""
     return sum(l * rows for l, rows in local_sort_classes(n, cfg))
+
+
+def local_sort_tiles(seg_id, done, n: int, cfg: model.SortConfig):
+    """Tiles the kernel engine's local sort runs on the final bucket state
+    ``(seg_id, done)``: Σ over size classes of ⌈occupied rows / tile
+    rows⌉.  Every engine reports it (``SortStats.local_sort_tiles``)."""
+    _, sizes, sortable = _done_segments(seg_id, done,
+                                        model.max_total_buckets(n, cfg))
+    return local_sort_tile_count(sizes, sortable, n, _local_row_len(n, cfg),
+                                 local_sort_classes(n, cfg))
+
+
+def _pass_loop_jnp(ukeys, vals, *, k, lo, nd, cfg, engine, adaptive):
+    """The jnp engines' counting passes from the initial bucket state:
+    ``(ukeys, vals, seg_id, done, executed passes, elided passes)``."""
+    n = ukeys.shape[0]
+    z = jnp.int32(0)
+
+    def cond(state):
+        done, p = state[3], state[5]
+        return (p < nd) & jnp.any(~done)
+
+    body = functools.partial(_counting_pass_jnp, k=k, d=cfg.d, lo=lo,
+                             a_max=model.max_active_buckets(n, cfg), nd=nd,
+                             cfg=cfg, engine=engine, adaptive=adaptive)
+    ukeys, vals, seg, done, _, _, p_exec, n_eld = lax.while_loop(
+        cond, body, (ukeys, vals, jnp.zeros((n,), jnp.int32),
+                     jnp.full((n,), n <= cfg.local_threshold),
+                     jnp.bool_(False), z, z, z))
+    return ukeys, vals, seg, done, p_exec, n_eld
 
 
 def _planned_passes(k: int, lo: int, d: int, max_passes: Optional[int]):
@@ -322,12 +365,10 @@ def _hybrid_sort_bits(ukeys, vals, cfg: model.SortConfig, k: int,
     nd = _planned_passes(k, lo, d, max_passes)
     a_max = model.max_active_buckets(n, cfg)
 
-    done0 = jnp.full((n,), n <= cfg.local_threshold)
-    seg0 = jnp.zeros((n,), jnp.int32)
-    nxt_valid0 = jnp.bool_(False)
-    z = jnp.int32(0)
-
     if engine == "kernel":
+        done0 = jnp.full((n,), n <= cfg.local_threshold)
+        seg0 = jnp.zeros((n,), jnp.int32)
+        z = jnp.int32(0)
         g_max = plan.max_region_blocks(n, cfg.kpb, a_max)
         leaves, treedef = jax.tree.flatten(vals)
         with jax.named_scope("ping_pong"):
@@ -349,22 +390,16 @@ def _hybrid_sort_bits(ukeys, vals, cfg: model.SortConfig, k: int,
         (ck, cv, ak, av, seg, done, _, _, _, p, p_exec, n_eld) = \
             lax.while_loop(cond, body,
                            (ck, cv, ak, av, seg0, done0, seg_hist0,
-                            jnp.zeros_like(seg_hist0), nxt_valid0,
+                            jnp.zeros_like(seg_hist0), jnp.bool_(False),
                             z, z, z))
         with jax.named_scope("unpad"):
             ukeys = fused.unpad(ck, n, ukeys.dtype)
             vals = jax.tree.unflatten(treedef,
                                       [fused.unpad(v, n) for v in cv])
     else:
-        def cond(state):
-            done, p = state[3], state[5]
-            return (p < nd) & jnp.any(~done)
-
-        body = functools.partial(_counting_pass_jnp, k=k, d=d, lo=lo,
-                                 a_max=a_max, nd=nd, cfg=cfg, engine=engine,
-                                 adaptive=adaptive)
-        ukeys, vals, seg, done, _, p, p_exec, n_eld = lax.while_loop(
-            cond, body, (ukeys, vals, seg0, done0, nxt_valid0, z, z, z))
+        ukeys, vals, seg, done, p_exec, n_eld = _pass_loop_jnp(
+            ukeys, vals, k=k, lo=lo, nd=nd, cfg=cfg, engine=engine,
+            adaptive=adaptive)
 
     needs_local = jnp.any(done)
     if engine == "kernel":
@@ -382,7 +417,8 @@ def _hybrid_sort_bits(ukeys, vals, cfg: model.SortConfig, k: int,
     sizes = jnp.bincount(seg, length=n if n else 1)
     stats = SortStats(counting_passes=p_exec, used_local_sort=needs_local,
                       num_segments=seg[-1] + 1 if n else jnp.int32(0),
-                      max_segment=sizes.max(), elided_passes=n_eld)
+                      max_segment=sizes.max(), elided_passes=n_eld,
+                      local_sort_tiles=local_sort_tiles(seg, done, n, cfg))
     return ukeys, vals, stats
 
 
@@ -424,7 +460,9 @@ def hybrid_sort(keys: jnp.ndarray, values: Any = None,
     Under ``jax.profiler`` the call is a host span ``hybrid_sort`` whose
     arguments are the plan's counters (``n``, ``key_bits``, ``engine``, the
     live window ``lo``/``hi``, ``planned_passes`` and, on the kernel engine,
-    ``local_sort_lanes``), with the children ``hybrid_sort.prologue``
+    ``local_sort_lanes``: the local sort's capacity, an upper bound; the
+    lanes it runs are at most ``stats.local_sort_tiles`` × the tile's
+    lanes), with the children ``hybrid_sort.prologue``
     (holding ``hybrid_sort.live_bit_window``, the host copy and bit
     reduce) and ``hybrid_sort.dispatch``.  Inside the program every stage
     sits in a named scope under ``hybrid_sort`` (``ping_pong``,
@@ -507,11 +545,13 @@ def hybrid_sort(keys: jnp.ndarray, values: Any = None,
 # (n, cfg): classes = len(local_sort_classes(n, cfg)), passes = ⌈k/d⌉ nominal
 # schedule slots, n_pad = fused.buffer_length(n, cfg.kpb), kb/vb = key/value
 # bytes, vals = payload leaves, g_max/B = descriptor rows / super-step width.
+# Loops: the counting-pass loop, then one tile loop per local-sort class,
+# each body holding one launch site.
 ANALYSIS_CONTRACT = {
     "entry": "repro.core.hybrid.hybrid_sort",
     "census": {
         "launch_total": "2 + classes",
-        "while_body_launches": "[1]",
+        "while_body_launches": "[1] * (1 + classes)",
         "fused_grid": "ceil_div(g_max, B)",
     },
     "sort_free": True,

@@ -1199,14 +1199,14 @@ def _resume(resume_from: str, *, spill_budget_bytes, interpret, faults,
 
 # --- contract declarations (verified by repro.analysis; see analysis/contracts)
 # §5 census + transfer tables: a chunk sort inherits the hybrid contract at
-# chunk size; a device merge round and a spill slab sweep are each ONE
+# chunk size (pass loop plus one tile loop per local-sort class); a device merge round and a spill slab sweep are each ONE
 # kway_merge_round launch moving exactly one read + one write sweep of the
 # (pad_length-sized) run/slab buffer.
 ANALYSIS_CONTRACTS = {
     "ooc_chunk_sort": {
         "entry": "repro.core.outofcore._sort_chunk",
         "census": {"launch_total": "2 + classes",
-                   "while_body_launches": "[1]"},
+                   "while_body_launches": "[1] * (1 + classes)"},
         "sort_free": True,
         "donation": {"fused_counting_pass": "1 + vals"},
         "transfer": {

@@ -25,9 +25,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels.histogram import radix_histogram
-from repro.kernels.bitonic import bitonic_sort_rows, bitonic_sort_rows_stable
+from repro.kernels.bitonic import (_STEP_ELEMS, bitonic_sort_rows,
+                                  bitonic_sort_rows_stable)
 
 
 def apply_run_copies(src: jnp.ndarray, dst: jnp.ndarray, tree):
@@ -74,24 +76,64 @@ def local_sort_class_plan(n: int, row_len: int, s_max: int,
     return tuple(classes)
 
 
-def _class_run_copies(keys, seg_start, seg_size, seg_sortable, l: int,
-                      rows: int, prev_l: int, interpret: bool):
-    """(src, dst) run copies sorting the flagged segments of one size class:
-    sizes in (prev_l, l], gathered into ``rows`` sentinel-padded rows."""
+def local_sort_tile_lanes(n: int, row_len: int) -> int:
+    """Lanes of one local-sort tile: a power of two of about n/32, and at
+    least one bitonic grid step and one row of the widest class.  A class of
+    width L runs tiles of ``T // L`` rows (fewer if its capacity is less),
+    so a sort of n keys runs a few dozen tiles whatever its classes.
+    Tiles of n/16 lanes crowd the distributed sort's merge searches out of
+    a TPU v5e's VMEM: the compiler then leaves the sorted keys in HBM."""
+    return max(_STEP_ELEMS, row_len, 1 << max(n // 32 - 1, 0).bit_length())
+
+
+def _class_plan(n: int, row_len: int, s: int, classes):
+    """(L, rows, prev_L, tile_rows) per class; class 0 catches every size
+    <= its L.  ``rows`` is the class's static capacity, at most ``s``."""
+    if classes is None:
+        classes = ((row_len, s),)
+    tile = local_sort_tile_lanes(n, row_len)
+    prev = [-1] + [l for l, _ in classes[:-1]]
+    out = []
+    for (l, rows), p in zip(classes, prev):
+        rows = min(rows, s)
+        out.append((l, rows, p, min(rows, max(1, tile // l))))
+    return out
+
+
+def _in_class(seg_size, seg_sortable, l: int, prev_l: int):
+    """The flagged segments of one size class: sizes in (prev_l, l]."""
+    return seg_sortable & (seg_size <= l) & (seg_size > prev_l)
+
+
+def _class_tiles(in_cls, rows: int, tile_rows: int):
+    """Tiles a class runs: its occupied rows, at most its capacity, in
+    tiles of ``tile_rows``."""
+    m = jnp.minimum(jnp.sum(in_cls, dtype=jnp.int32), rows)
+    return (m + tile_rows - 1) // tile_rows
+
+
+def local_sort_tile_count(seg_size: jnp.ndarray, seg_sortable: jnp.ndarray,
+                          n: int, row_len: int, classes=None):
+    """Tiles ``segmented_local_sort`` runs on these segments of n keys:
+    Σ over classes of ⌈occupied rows / tile rows⌉ (int32 scalar)."""
+    total = jnp.int32(0)
+    for l, rows, prev, tile_rows in _class_plan(n, row_len,
+                                                seg_size.shape[0], classes):
+        total += _class_tiles(_in_class(seg_size, seg_sortable, l, prev),
+                              rows, tile_rows)
+    return total
+
+
+def _tile_run_copies(keys, starts, sizes, l: int, interpret: bool):
+    """(src, dst) run copies sorting one tile: each segment ``(start,
+    size)`` gathered into a sentinel-padded row of width ``l`` (size 0: an
+    empty row, start n)."""
     n = keys.shape[0]
-    s = seg_start.shape[0]
     sentinel = ~jnp.zeros((), keys.dtype)
     with jax.named_scope("rows"):
-        in_cls = seg_sortable & (seg_size <= l) & (seg_size > prev_l)
-        rsel = jnp.nonzero(in_cls, size=min(rows, s), fill_value=s)[0]
-        sel = jnp.clip(rsel, 0, s - 1)
-        valid = rsel < s
-        starts_c = jnp.where(valid, seg_start[sel], n)
-        sizes_c = jnp.where(valid, seg_size[sel], 0)
-
         lane = jnp.arange(l, dtype=jnp.int32)
-        gidx = starts_c[:, None] + lane[None, :]              # (rows, L)
-        lv = lane[None, :] < sizes_c[:, None]
+        gidx = starts[:, None] + lane[None, :]                # (rows, L)
+        lv = lane[None, :] < sizes[:, None]
         safe = jnp.clip(gidx, 0, max(n - 1, 0))
         row_keys = jnp.where(lv, keys[safe], sentinel)
         idx = jnp.where(lv, gidx, n).astype(jnp.int32)
@@ -105,12 +147,35 @@ def _class_run_copies(keys, seg_start, seg_size, seg_sortable, l: int,
         return si.reshape(-1), dst.reshape(-1)
 
 
-def _class_plan(row_len: int, s: int, classes):
-    """(L, rows, prev_L) per class; class 0 catches every size <= its L."""
-    if classes is None:
-        classes = ((row_len, s),)
-    prev = [-1] + [l for l, _ in classes[:-1]]
-    return [(l, rows, p) for (l, rows), p in zip(classes, prev)]
+def _sort_class(tree, seg_start, seg_size, seg_sortable, l: int, rows: int,
+                prev_l: int, tile_rows: int, interpret: bool):
+    """Sort one size class's flagged segments in place, a tile of
+    ``tile_rows`` occupied rows at a time."""
+    n = jax.tree.leaves(tree)[0].shape[0]
+    s = seg_start.shape[0]
+    with jax.named_scope("rows"):
+        in_cls = _in_class(seg_size, seg_sortable, l, prev_l)
+        rsel = jnp.nonzero(in_cls, size=rows, fill_value=s)[0]
+        slots = -(-rows // tile_rows) * tile_rows
+        rsel = jnp.pad(rsel, (0, slots - rows), constant_values=s)
+        valid = rsel < s
+        sel = jnp.clip(rsel, 0, s - 1)
+        starts = jnp.where(valid, seg_start[sel], n)
+        sizes = jnp.where(valid, seg_size[sel], 0)
+        tiles = _class_tiles(in_cls, rows, tile_rows)
+
+    def tile(t, tree):
+        # a while body restarts the scope stack: name the stage again
+        with jax.named_scope("local_sort"):
+            at = t * tile_rows
+            src, dst = _tile_run_copies(
+                jax.tree.leaves(tree)[0],
+                lax.dynamic_slice_in_dim(starts, at, tile_rows),
+                lax.dynamic_slice_in_dim(sizes, at, tile_rows), l, interpret)
+            with jax.named_scope("copy_back"):
+                return apply_run_copies(src, dst, tree)
+
+    return lax.fori_loop(0, tiles, tile, tree)
 
 
 def segmented_local_sort(tree, seg_start: jnp.ndarray, seg_size: jnp.ndarray,
@@ -123,25 +188,27 @@ def segmented_local_sort(tree, seg_start: jnp.ndarray, seg_size: jnp.ndarray,
     rows by (key, global index) — so pads (index n) lose every tie and the
     order is stable — and run-copies the sorted prefix back over the
     segment, carrying every leaf.  Unflagged segments are untouched.  The
-    stages sit in the named scopes ``rows`` (the gathers into class
-    tables), ``bitonic`` and ``copy_back`` (the run copies).
+    stages sit in the named scopes ``rows`` (the gathers into tiles),
+    ``bitonic`` and ``copy_back`` (the run copies), each under
+    ``local_sort`` inside the tile loop.
 
     ``classes`` is an optional size-class plan (``local_sort_class_plan``):
     segments are binned into power-of-two row widths — one fixed-shape
-    bitonic launch per class — so a 3-key bucket sorts in a ``min_len`` row
-    instead of a ``row_len`` one.  ``None`` keeps the single worst-case
-    table: one class of width ``row_len`` with a row per segment slot.
-    Each class's copies are applied before the next class gathers, so only
-    one class table (about 2n lanes at the widest bound) is live at a time
-    — what lets a 2^26-key sort fit one chip.  Classes cover disjoint
-    segments, so the order of application does not matter.
+    bitonic launch site per class — so a 3-key bucket sorts in a ``min_len``
+    row instead of a ``row_len`` one.  ``None`` keeps the single worst-case
+    class: width ``row_len`` with a row per segment slot.  A class's static
+    capacity bounds its rows, but only its occupied rows run: a device loop
+    over tiles of ``local_sort_tile_lanes(n, row_len) // L`` rows, as many
+    as ``local_sort_tile_count`` gives (an empty class runs none).  Each
+    tile's copies are applied before the next tile gathers, so one tile is
+    live at a time.  Classes and tiles cover disjoint segments, so the
+    order of application does not matter.
     """
-    for l, rows, prev in _class_plan(row_len, seg_start.shape[0], classes):
-        keys = jax.tree.leaves(tree)[0]
-        src, dst = _class_run_copies(keys, seg_start, seg_size, seg_sortable,
-                                     l, rows, prev, interpret)
-        with jax.named_scope("copy_back"):
-            tree = apply_run_copies(src, dst, tree)
+    n = jax.tree.leaves(tree)[0].shape[0]
+    for l, rows, prev, tile_rows in _class_plan(n, row_len,
+                                                seg_start.shape[0], classes):
+        tree = _sort_class(tree, seg_start, seg_size, seg_sortable, l, rows,
+                           prev, tile_rows, interpret)
     return tree
 
 

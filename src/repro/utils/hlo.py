@@ -199,10 +199,11 @@ def launch_census(jaxpr) -> Dict[str, object]:
     counts.
 
     The two structural invariants of the sort pipelines read straight off
-    this: the fused hybrid engine traces to ``{"total": 2 +
-    len(core.hybrid.local_sort_classes(n, cfg)), "while_bodies": [1]}``
-    (prologue + ONE launch per counting pass + one bitonic launch per
-    local-sort size class), and every
+    this: the fused hybrid engine with ``c =
+    len(core.hybrid.local_sort_classes(n, cfg))`` traces to ``{"total": 2 +
+    c, "while_bodies": [1] * (1 + c)}`` (prologue + ONE launch per counting
+    pass + one bitonic launch site per local-sort size class, run once per
+    tile of that class's loop), and every
     out-of-core merge *round* — a host-driven jit with no device loop —
     traces to ``{"total": 1, "while_bodies": []}``: one ``pallas_call`` per
     round, ``⌈log_K(runs)⌉`` rounds per sort (§5).  Any binary-search loop
@@ -238,10 +239,11 @@ def pallas_grid_sizes(jaxpr):
 def while_body_pallas_launches(jaxpr):
     """Launch sites inside each while-loop body, outermost-first.
 
-    For the fused hybrid engine this returns ``[1]``: one Pallas launch per
-    counting pass (the loop body), with the prologue histogram and the local
-    sort outside the loop.  Loops inside a kernel body run within its one
-    launch and are not listed.
+    For the fused hybrid engine this returns ``[1] * (1 + classes)``: one
+    Pallas launch per counting pass (the pass loop's body), then one bitonic
+    launch per tile in each local-sort class's tile loop; the prologue
+    histogram sits outside every loop.  Loops inside a kernel body run
+    within its one launch and are not listed.
     """
     if hasattr(jaxpr, "jaxpr"):
         jaxpr = jaxpr.jaxpr

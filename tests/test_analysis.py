@@ -48,7 +48,7 @@ def test_expected_census_matches_formulas():
     p = contracts.hybrid_params(2048, contracts.TCFG)
     got = contracts.expected_census("hybrid_sort", p)
     assert got["total"] == 2 + p["classes"]
-    assert got["while_bodies"] == [1]
+    assert got["while_bodies"] == [1] * (1 + p["classes"])
 
 
 def test_expr_evaluator_rejects_unsafe_forms():

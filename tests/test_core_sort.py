@@ -224,6 +224,23 @@ def test_vmapped_kernel_dispatch_matches_argsort(rng):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), name
 
 
+def test_vmapped_kernel_sort_matches_argsort(rng):
+    """hybrid_sort vmapped over rows of different entropy: the local sort's
+    tile loops run as one batched loop over rows that need different
+    counts of tiles, and every row agrees with the argsort engine."""
+    x = np.stack([entropy_keys(rng, 3000, a) for a in (0, 3)])
+    v = np.tile(np.arange(3000, dtype=np.int32), (2, 1))
+
+    def sort(engine):
+        k, vv = jax.jit(jax.vmap(lambda a, b: hybrid_sort(
+            a, b, cfg=TCFG, engine=engine)))(jnp.asarray(x), jnp.asarray(v))
+        return np.asarray(k), np.asarray(vv)
+
+    (gk, gv), (wk, wv) = sort("kernel"), sort("argsort")
+    assert np.array_equal(gk, np.sort(x, axis=1))
+    assert gk.tobytes() == wk.tobytes() and gv.tobytes() == wv.tobytes()
+
+
 def test_merge_sorted_and_multiway(rng):
     a = np.sort(rng.integers(0, 1000, 257).astype(np.uint32))
     b = np.sort(rng.integers(0, 1000, 511).astype(np.uint32))

@@ -166,6 +166,54 @@ def test_segmented_local_sort_size_classes(rng):
     assert np.array_equal(got, ref)
 
 
+# n = 8192 keys run tiles of 8192 lanes: 256 rows of class 0 (L = 32),
+# 128 rows of class 1 (L = 64)
+TILE_CASES = {
+    # name: (segment sizes, flags or None for all set, tiles per class)
+    "k_tiles": ([16] * 512, None, (2, 0)),
+    "k_tiles_plus_one_row": ([15] * 512 + [16], None, (3, 0)),
+    "empty_class": ([64] * 128, None, (0, 1)),
+    "both_classes": ([20] * 300 + [40] * 50, None, (2, 1)),
+    "every_class_empty": ([16] * 512, [False] * 512, (0, 0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILE_CASES))
+def test_segmented_local_sort_tiles(rng, case):
+    """Each class runs its occupied rows a tile at a time — whole tiles,
+    one row over, an empty class, nothing flagged — and sorts every flagged
+    segment stably, values riding along, whatever the tiling."""
+    from repro.kernels.ops import (local_sort_class_plan,
+                                   local_sort_tile_count,
+                                   local_sort_tile_lanes)
+
+    n, row_len = 8192, 64
+    sizes_np, flags_np, per_class = TILE_CASES[case]
+    starts_np = np.concatenate([[0], np.cumsum(sizes_np)]).astype(np.int32)
+    sizes_np = np.append(sizes_np, n - starts_np[-1]).astype(np.int32)
+    flags_np = np.append(np.ones(len(sizes_np) - 1, bool) if flags_np is None
+                         else flags_np, False)        # unflagged tail
+    classes = local_sort_class_plan(n, row_len, s_max=len(sizes_np))
+    assert local_sort_tile_lanes(n, row_len) == 8192
+    assert [l for l, _ in classes] == [32, 64]
+    x = rng.integers(0, 1 << 12, n, dtype=np.uint32)  # many equal keys
+    x[7] = 0xFFFFFFFF                                 # collides with the pad
+    v = np.arange(n, dtype=np.int32)
+    args = (jnp.asarray(starts_np), jnp.asarray(sizes_np),
+            jnp.asarray(flags_np))
+    k, vv = segmented_local_sort((jnp.asarray(x), jnp.asarray(v)), *args,
+                                 row_len, interpret=True, classes=classes)
+    want_k, want_v = x.copy(), v.copy()
+    for st_, sz, fl in zip(starts_np, sizes_np, flags_np):
+        if fl:
+            order = st_ + np.argsort(x[st_:st_ + sz], kind="stable")
+            want_k[st_:st_ + sz], want_v[st_:st_ + sz] = x[order], v[order]
+    assert np.array_equal(np.asarray(k), want_k)
+    assert np.array_equal(np.asarray(vv), want_v)
+    assert int(local_sort_tile_count(args[1], args[2], n, row_len,
+                                     classes)) == sum(per_class)
+
+
 def test_local_sort_class_plan_bounds():
     """Class widths double from min_len to row_len; capacities are the
     static counting bounds (class 0: every segment slot; class i: at most

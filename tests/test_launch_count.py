@@ -4,11 +4,11 @@ The point of ``kernels.fused`` is that one counting pass is ONE Pallas
 launch (§4.3–§4.4: partition + scatter + next-pass histogram fused), so the
 whole hybrid sort traces to a fixed set of launch sites — the prologue
 histogram, the per-pass fused launch inside the while loop, and one bitonic
-local-sort launch per size class (``core.hybrid.local_sort_classes``) —
-independent of the data and the executed pass count.  Batched grid steps
-(``plan.pack_region_blocks``) shrink the fused launch's *grid* from g_max
-to ⌈g_max/B⌉ but must not change the launch count: the while body stays
-exactly one ``pallas_call``.  ``utils.hlo`` counts ``pallas_call`` sites in
+local-sort launch per size class (``core.hybrid.local_sort_classes``), each
+inside that class's tile loop — independent of the data and the executed
+pass count.  Batched grid steps (``plan.pack_region_blocks``) shrink the
+fused launch's *grid* from g_max to ⌈g_max/B⌉ but must not change the
+launch count: each while body stays exactly one ``pallas_call``.  ``utils.hlo`` counts ``pallas_call`` sites in
 the jaxpr and reads their grids (interpret mode has no custom-call in the
 lowered HLO; on hardware ``pallas_custom_call_count`` covers the text).
 """
@@ -19,6 +19,7 @@ import pytest
 
 from repro.analysis import contracts as an
 from repro.core import SortConfig, hybrid_sort, lsd_sort, model, plan
+from repro.core.hybrid import local_sort_classes
 from repro.core.outofcore import _sort_chunk, merge_round
 from repro.core.segmented import counting_partition
 from repro.kernels import merge as kmerge
@@ -35,6 +36,12 @@ def _hybrid_launches(n, cfg):
     return an.expected_census("hybrid_sort", an.hybrid_params(n, cfg))["total"]
 
 
+def _hybrid_loops(n, cfg):
+    """The pass loop's one launch, then one launch in each local-sort
+    class's tile loop."""
+    return [1] * (1 + len(local_sort_classes(n, cfg)))
+
+
 def test_hybrid_fused_engine_one_launch_per_pass():
     """THE acceptance gate: the counting-pass loop body contains exactly one
     pallas_call, and the whole trace exactly prologue + pass + the static
@@ -43,7 +50,7 @@ def test_hybrid_fused_engine_one_launch_per_pass():
         jx = jax.make_jaxpr(
             lambda a: hybrid_sort(a, cfg=TCFG, engine="kernel"))(
                 jnp.zeros(n, jnp.uint32))
-        assert hlo.while_body_pallas_launches(jx) == [1], n
+        assert hlo.while_body_pallas_launches(jx) == _hybrid_loops(n, TCFG), n
         assert hlo.pallas_launch_count(jx) == _hybrid_launches(n, TCFG), n
 
 
@@ -52,7 +59,7 @@ def test_hybrid_fused_launches_with_values_and_stats():
     v = {"a": jnp.zeros(2048, jnp.int32), "b": jnp.zeros(2048, jnp.float32)}
     jx = jax.make_jaxpr(lambda a, b: hybrid_sort(
         a, b, cfg=TCFG, engine="kernel", return_stats=True))(x, v)
-    assert hlo.while_body_pallas_launches(jx) == [1]
+    assert hlo.while_body_pallas_launches(jx) == _hybrid_loops(2048, TCFG)
     assert hlo.pallas_launch_count(jx) == _hybrid_launches(2048, TCFG)
 
 
@@ -69,7 +76,7 @@ def test_hybrid_batched_grid_steps_shrink_the_grid():
         g_max = plan.max_region_blocks(n, cfg.kpb, a_max)
         jx = jax.make_jaxpr(
             lambda a: hybrid_sort(a, cfg=cfg, engine="kernel"))(x)
-        assert hlo.while_body_pallas_launches(jx) == [1], b
+        assert hlo.while_body_pallas_launches(jx) == _hybrid_loops(n, cfg), b
         assert hlo.pallas_launch_count(jx) == _hybrid_launches(n, cfg), b
         grids = hlo.pallas_grid_sizes(jx)
         # trace order: prologue histogram, fused pass (while body), classes
@@ -139,9 +146,10 @@ def test_ooc_chunk_sort_keeps_one_launch_per_pass():
     jx = jax.make_jaxpr(
         lambda a: _sort_chunk(a, (), TCFG, "kernel", True))(
             jnp.zeros(256, jnp.uint32))
-    assert hlo.while_body_pallas_launches(jx) == [1]
+    loops = _hybrid_loops(256, TCFG)
+    assert hlo.while_body_pallas_launches(jx) == loops
     assert hlo.pallas_launch_count(jx) == total
-    assert hlo.launch_census(jx) == {"total": total, "while_bodies": [1]}
+    assert hlo.launch_census(jx) == {"total": total, "while_bodies": loops}
 
 
 def test_spill_slab_sweep_single_launch_and_sort_free():
@@ -260,7 +268,8 @@ def _dist_launches(n_local, num_chunks, max_attempts, cfg):
 
 def test_distributed_shard_body_launch_census():
     """ONE pallas_call per counting pass inside the shard_map body — for
-    the local chunk sorts (their while bodies stay [1]), every
+    the local chunk sorts (a pass loop and a tile loop per class, one
+    launch each), every
     cond-guarded exchange attempt's bucketing pass, and the compaction
     pass — at every (chunks, attempts) shape, keys-only and KV."""
     from repro.core.distributed import make_distributed_sort
@@ -275,14 +284,15 @@ def test_distributed_shard_body_launch_census():
         census = hlo.launch_census(jax.make_jaxpr(fn)(x))
         expected = _dist_launches(n_local, num_chunks, max_attempts, TCFG)
         assert census["total"] == expected, (num_chunks, max_attempts)
-        assert census["while_bodies"] == [1] * num_chunks, num_chunks
+        assert census["while_bodies"] == num_chunks * _hybrid_loops(
+            n_local // num_chunks, TCFG), num_chunks
     # KV payloads ride as one int32 rank per key: census unchanged
     fn = make_distributed_sort(mesh, "data", cfg=TCFG, engine="kernel",
                                num_chunks=2, max_attempts=3)
     census = hlo.launch_census(
         jax.make_jaxpr(lambda k, v: fn(k, v))(x, jnp.zeros_like(x)))
     assert census["total"] == _dist_launches(n_local, 2, 3, TCFG)
-    assert census["while_bodies"] == [1, 1]
+    assert census["while_bodies"] == 2 * _hybrid_loops(n_local // 2, TCFG)
 
 
 def test_distributed_retry_replay_conserves_per_pass_launches():
@@ -301,7 +311,8 @@ def test_distributed_retry_replay_conserves_per_pass_launches():
                                    num_chunks=num_chunks,
                                    max_attempts=max_attempts)
         census = hlo.launch_census(jax.make_jaxpr(fn)(x))
-        assert census["while_bodies"] == [1] * num_chunks, max_attempts
+        assert census["while_bodies"] == num_chunks * _hybrid_loops(
+            n_local // num_chunks, TCFG), max_attempts
         totals.append(census["total"])
     assert np.diff(totals).tolist() == [2 * num_chunks] * 2
 

@@ -5,6 +5,7 @@ The compiled-for-v5e checks (kernel names and scopes of the Mosaic path)
 live in ``tests/test_tpu_compile.py`` beside its described chip; these run
 on the CPU, the kernel engine in interpret mode.
 """
+import functools
 import glob
 
 import jax
@@ -15,6 +16,7 @@ from jax.sharding import Mesh
 
 from repro.core import SortConfig, hybrid, hybrid_sort, model
 from repro.core.distributed import make_distributed_sort
+from repro.kernels import ops
 
 # passes, bucket merges and every local-sort class fire at this size
 CFG = SortConfig(d=8, kpb=64, local_threshold=48, merge_threshold=32)
@@ -109,3 +111,83 @@ def test_local_sort_lanes_sums_the_class_tables(n):
     classes = hybrid.local_sort_classes(n, cfg)
     assert hybrid.local_sort_lanes(n, cfg) == sum(l * rows
                                                   for l, rows in classes)
+
+
+# --- the local sort's tile loop ---------------------------------------------
+
+_compiled = {}
+
+
+def _compiled_names():
+    """Instruction -> op_name of the compiled kernel-engine program, read
+    the way the benchmark reads a chip's program."""
+    from bench import stages
+    if not _compiled:
+        keys = jax.ShapeDtypeStruct((N,), jnp.uint32)
+        text = hybrid._hybrid_sort_bits.lower(
+            keys, keys, CFG, 32, False, None, "kernel", True, lo=0,
+            adaptive=True).compile().as_text()
+        _compiled.update(stages.scope_map(text))
+    return _compiled
+
+
+@pytest.mark.parametrize("scope", ["local_sort/rows", "local_sort/bitonic",
+                                   "local_sort/copy_back"])
+def test_tile_loop_ops_keep_their_stage_names(scope):
+    """Inside a class's tile loop the ops still sit under their stage, so
+    the benchmark's scope readers find them."""
+    from bench import stages
+    hits = [o for o in _compiled_names().values() if stages.in_scope(o, scope)]
+    assert any("/while/body/local_sort/" in o for o in hits)
+
+
+def _final_buckets(x, cfg):
+    """(seg_id, done) after the argsort engine's counting passes, on the
+    schedule ``hybrid_sort`` plans for the concrete uint32 keys ``x``."""
+    lo, hi = hybrid.live_bit_window(x)
+    loop = jax.jit(functools.partial(
+        hybrid._pass_loop_jnp, k=hi, lo=lo,
+        nd=hybrid._planned_passes(hi, lo, cfg.d, None), cfg=cfg,
+        engine="argsort", adaptive=True))
+    _, _, seg, done, _, _ = loop(jnp.asarray(x), ())
+    return np.asarray(seg), np.asarray(done)
+
+
+def _numpy_tiles(seg, done, cfg):
+    """Σ over size classes of ⌈occupied rows / tile rows⌉, from the final
+    segments: a class of width L holds the done segments of size in
+    (previous L, L] and runs tiles of ``T // L`` rows, at most its
+    capacity."""
+    n = seg.size
+    starts = np.flatnonzero(np.r_[True, seg[1:] != seg[:-1]])
+    sizes = np.diff(np.r_[starts, n])
+    sortable = done[starts]
+    classes = hybrid.local_sort_classes(n, cfg)
+    tile = ops.local_sort_tile_lanes(n, classes[-1][0])
+    tiles, prev = 0, -1
+    for l, rows in classes:
+        occupied = int(np.sum(sortable & (sizes > prev) & (sizes <= l)))
+        tile_rows = min(rows, max(1, tile // l))
+        tiles += -(-min(occupied, rows) // tile_rows)
+        prev = l
+    return tiles
+
+
+@pytest.mark.parametrize("keys", ["uniform", "ands3", "constant"])
+def test_local_sort_tiles_counts_the_occupied_rows(keys):
+    """Every engine reports the tiles the kernel engine's local sort runs,
+    and sorts the same; constant keys leave no done bucket, so no tile, and
+    ands3 keys leave equal-key buckets that the local sort skips."""
+    n = 20000
+    rng = np.random.default_rng(15)
+    draw = lambda: rng.integers(0, 2**32, n, dtype=np.uint32)
+    x = {"uniform": draw, "ands3": lambda: draw() & draw() & draw() & draw(),
+         "constant": lambda: np.full(n, 0xC0FFEE, np.uint32)}[keys]()
+    seg, done = _final_buckets(x, CFG)
+    want = _numpy_tiles(seg, done, CFG)
+    assert (want == 0) == (keys == "constant")
+    for engine in ("kernel", "argsort"):
+        out, stats = hybrid_sort(jnp.asarray(x), cfg=CFG, engine=engine,
+                                 return_stats=True)
+        assert np.array_equal(np.asarray(out), np.sort(x)), engine
+        assert int(stats.local_sort_tiles) == want, engine
